@@ -92,12 +92,11 @@ def bench_step(sizes=(300, 600, 1000), iters: int = 400, reps: int = 5):
     step (``polish=0``) — what each backend charges the pipeline per
     iteration."""
     import jax
-    from jax.experimental import enable_x64
 
     from repro.kernels.pdhg_fused import pdhg_fused
 
     per_size = {}
-    with enable_x64():
+    with jax.enable_x64(True):
         ref = LP._jitted_kernel(False, "reference")
         fused = jax.jit(functools.partial(pdhg_fused, polish=0),
                         static_argnums=(1,))
@@ -129,12 +128,11 @@ def bench_step(sizes=(300, 600, 1000), iters: int = 400, reps: int = 5):
 def bench_solve(n_users: int = 1000, iters: int = 1000, reps: int = 3):
     """Production solve: mixed-precision fused vs all-f64 reference."""
     import jax
-    from jax.experimental import enable_x64
 
     from repro.kernels.pdhg_fused import POLISH_TAIL
 
     inst = _single_inst(n_users)
-    with enable_x64():
+    with jax.enable_x64(True):
         import jax.numpy as jnp
 
         data = jax.tree_util.tree_map(jnp.asarray, LP.pdhg_data(inst))

@@ -22,6 +22,9 @@ def _emit_offline(name, res):
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     t0 = time.time()
     print("name,us_per_call,derived")
 

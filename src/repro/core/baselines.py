@@ -179,10 +179,9 @@ def draw_baseline_uniforms(key, N, M, U, n_seeds=1, batch=None):
     With ``batch`` given, every tensor gains a leading batch axis.
     """
     import jax
-    from jax.experimental import enable_x64
 
     lead = (n_seeds,) if batch is None else (batch, n_seeds)
-    with enable_x64():
+    with jax.enable_x64(True):
         k = jax.random.PRNGKey(key) if isinstance(key, int) else key
         k1, k2, k3 = jax.random.split(k, 3)
         u_perm = jax.random.uniform(k1, lead + (N, M), dtype=np.float64)
@@ -379,9 +378,9 @@ def _train_gatmarl(inst: JDCRInstance, seed: int, episodes: int = 150):
     """REINFORCE training, pinned to float64 (``enable_x64``) so the
     learned params — and therefore the gated comparison ratio — are
     identical whether or not the process runs under JAX_ENABLE_X64."""
-    from jax.experimental import enable_x64
+    import jax
 
-    with enable_x64():
+    with jax.enable_x64(True):
         return _train_gatmarl_x64(inst, seed, episodes)
 
 
@@ -485,12 +484,12 @@ def gat_rollout_host(inst: JDCRInstance, params, feats=None, adj=None):
     (possibly padded) features, then the NumPy fill + best-precision route.
     ``feats``/``adj`` default to the window's own unpadded arrays; pass the
     stacked grid's padded versions to oracle the device kernel."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     feats = gat_features(inst) if feats is None else feats
     adj = gat_adj(inst, n_pad=len(feats)) if adj is None else adj
-    with enable_x64():
+    with jax.enable_x64(True):
         logits = np.asarray(_gat_forward(params, jnp.asarray(feats),
                                          jnp.asarray(adj)))
     actions = np.argmax(

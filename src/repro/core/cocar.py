@@ -140,9 +140,9 @@ def offline_pipeline_device(stacked: StackedWindows, u_cat, u_phi,
     (B,S) arrays), and ``lp_obj (B,)`` — plus batched solver curves under
     ``lp_diag`` when ``diagnostics`` is on.
     """
-    from jax.experimental import enable_x64
+    import jax
 
-    with enable_x64():
+    with jax.enable_x64(True):
         out = _pipeline_jitted(lp_backend, bool(diagnostics))(
             stacked.data, u_cat, u_phi, int(pdhg_iters), int(n_seeds))
     return {k: ({kk: np.asarray(vv) for kk, vv in v.items()}
@@ -348,14 +348,14 @@ def policy_grid_device(stacked: StackedWindows, seed: int = 0,
     solution (``spr3_frac``) for the host oracle — plus CoCaR's batched
     solver curves under ``lp_diag`` when ``diagnostics`` is on.
     """
-    from jax.experimental import enable_x64
+    import jax
 
     uniforms = uniforms if uniforms is not None else \
         policy_uniforms(stacked, seed, n_seeds, best_of)
     gat = gat if gat is not None else \
         gat_grid_policies(stacked, seed, episodes)
     gat_params, gat_feats, gat_adj = gat
-    with enable_x64():
+    with jax.enable_x64(True):
         out = _policy_jitted(lp_backend, bool(diagnostics))(
             stacked.data, *uniforms, gat_params, gat_feats, gat_adj,
             int(pdhg_iters), int(n_seeds))

@@ -53,12 +53,11 @@ def draw_rounding_uniforms(key, n_trials, N, M, U, H, batch=None):
     ``batch`` axis when given.  Both engines consume these *same* numbers.
     """
     import jax
-    from jax.experimental import enable_x64
 
     shape = (n_trials, N, M) if batch is None else (batch, n_trials, N, M)
     shape_phi = shape[:-2] + (N, U, H) if batch is None \
         else (batch, n_trials, N, U, H)
-    with enable_x64():
+    with jax.enable_x64(True):
         k = jax.random.PRNGKey(key) if isinstance(key, int) else key
         k1, k2 = jax.random.split(k)
         u_cat = jax.random.uniform(k1, shape, dtype=np.float64)

@@ -252,12 +252,13 @@ def named(mesh: Mesh, spec_tree):
 # ---------------------------------------------------------------------------
 
 def _ambient_mesh():
-    m = jax.interpreters.pxla.thread_resources.env.physical_mesh
+    m = jax.sharding.get_abstract_mesh()
     return None if m.empty else m
 
 
 def hint(x, *axes):
-    """with_sharding_constraint resolved against the ambient mesh.
+    """with_sharding_constraint resolved against the ambient mesh (the
+    one ``jax.set_mesh`` installs).
 
     axes entries: "batch" (shard over ("pod","data") when divisible),
     "model" (shard over "model" when divisible), or None.  Outside a mesh
@@ -276,8 +277,7 @@ def hint(x, *axes):
             spec.append("model")
         else:
             spec.append(None)
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(m, P(*spec)))
+    return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
 def hint_btd(h):

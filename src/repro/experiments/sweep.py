@@ -26,8 +26,8 @@ the other paper tables; run standalone with
     PYTHONPATH=src python -m repro.experiments.sweep            # offline
     PYTHONPATH=src python -m repro.experiments.sweep --online   # online
 
-``--shard`` partitions any of the grids across a host-device mesh via
-the ``repro.scale`` executor (run under
+``--shard`` partitions any of the grids across a device mesh via the
+``repro.scale`` executor (the chips of a TPU host; on a CPU run under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=K``); ``--devices``
 and ``--chunk`` tune the mesh width and streaming chunk.
 
@@ -379,6 +379,9 @@ def main(online: bool = False, backend: str = "device", n_seeds: int = 1,
 if __name__ == "__main__":
     import argparse
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description="scenario-grid sweeps")
     ap.add_argument("--online", action="store_true",
                     help="trace-family grid through the scan engine")
@@ -388,10 +391,10 @@ if __name__ == "__main__":
     ap.add_argument("--host", action="store_true",
                     help="NumPy round+repair reference loop")
     ap.add_argument("--shard", action="store_true",
-                    help="partition the grid across a host-device mesh "
-                         "(repro.scale; run under XLA_FLAGS="
-                         "--xla_force_host_platform_device_count=K for K "
-                         "virtual devices)")
+                    help="partition the grid across a device mesh "
+                         "(repro.scale; the chips of a TPU host, or on a "
+                         "CPU K virtual devices under XLA_FLAGS="
+                         "--xla_force_host_platform_device_count=K)")
     ap.add_argument("--devices", type=int, default=None,
                     help="mesh width for --shard (default: all devices)")
     ap.add_argument("--chunk", type=int, default=0,

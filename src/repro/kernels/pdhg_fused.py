@@ -6,33 +6,41 @@ einsums, separate strided reductions per dual family, and a dozen
 elementwise passes.  This module is the fused production path behind
 ``solve_lp_pdhg(..., backend="pallas")``:
 
-  * **one step, restructured** (``_fused_step``): the cache↔route coupling
-    ``x_a`` and its transpose each become a single real GEMM against the
-    one-hot user→model matrix (bit-identical to the reference's gather —
-    one-hot rows contract exactly one term per output), the three per-user
-    dual reductions run contiguously over a ``(U, N·H)`` relayout, and the
-    routing prox folds ``tau_A`` into precomputed ``tau_A·T`` / ``tau_A·L``
-    tensors — the same Chambolle–Pock math (docs/algorithms.md Sec. 3),
-    ~3x fewer memory passes;
+  * **one step, restructured** (``_fused_step``) on a 2-D *row layout*:
+    every per-(BS, exit) tensor is a matrix with one row per (n, h) pair,
+    ``R = N·(H+1)`` rows, exit 0 included.  The cache state is
+    ``x (R, M)``, the routing state and its coupling dual are
+    ``A, y_ax (R, U)`` with the exit-0 rows pinned at exactly 0 (zero
+    step sizes), and the per-user duals are ``(1, U)`` rows.  The
+    cache↔route coupling ``x_a`` and its transpose are each one real
+    GEMM against the one-hot model→user matrix at ``precision=HIGHEST``
+    (bit-identical to the reference's gather: one-hot rows contract
+    exactly one term per output, and HIGHEST splits an f32 operand into
+    bf16 pieces that sum back to it exactly); the per-BS sums and
+    broadcasts are GEMMs against the 0/1 block matrix ``E (N, R)``; the
+    routing prox folds ``tau_A`` into precomputed ``tau_A·T`` /
+    ``tau_A·L`` tensors.  The same Chambolle–Pock math
+    (docs/algorithms.md Sec. 3) with no scatter, no 1-D state and no
+    reshape inside the step — the layout Mosaic compiles for the TPU;
   * **two engines over the same step**: ``engine="scan"`` wraps the step
-    in ``lax.scan`` (the XLA path CPU CI measures), ``engine="pallas"``
-    keeps the whole state resident in VMEM scratch across a *block* of
-    iterations per grid step (``ssm_scan``-style sequential grid), so the
-    primal/dual tensors never round-trip HBM between iterations.  Both
-    engines execute the identical jnp expressions on the identical state
-    layout; what separates them is only XLA's per-compilation FMA
-    contraction, so interpret-mode Pallas agrees with the scan engine to
-    ≤1e-12 in pure f64 and to f32-ulp noise (~1e-7) through the mixed
-    sweep — and the *decisions* derived from either are bit-identical,
-    the conformance contract ``tests/test_pdhg_fused.py`` enforces;
+    in ``lax.scan``, ``engine="pallas"`` keeps the whole state resident
+    in VMEM scratch across a *block* of iterations per grid step
+    (sequential grid), so the primal/dual tensors never round-trip HBM
+    between iterations.  Both engines execute the identical jnp
+    expressions on the identical state layout; what separates them is
+    only per-compilation FMA contraction, so interpret-mode Pallas agrees
+    with the scan engine to f32-ulp noise (~1e-7) through the sweep — and
+    the *decisions* derived from either are bit-identical, the
+    conformance contract ``tests/test_pdhg_fused.py`` enforces;
   * **mixed precision** (``polish``): the inner sweep runs in float32,
     then the last ``polish`` iterations re-run the same fused step in
-    float64 on the carried state.  Decisions downstream (rounding, repair,
-    winning trials) are gated on ~1e-15-scale comparisons of *uniforms vs
-    thresholds*; the fused path preserves them because (a) the float64
-    tail pins every saturated coordinate back to the exact 0/1 the
-    reference reaches, and (b) the residual fractional gap is orders of
-    magnitude below the rounding-threshold margins, which
+    float64 on the carried state — always on the scan engine, since
+    Mosaic has no float64.  Decisions downstream (rounding, repair,
+    winning trials) are gated on ~1e-15-scale comparisons of *uniforms
+    vs thresholds*; the fused path preserves them because (a) the
+    float64 tail pins every saturated coordinate back to the exact 0/1
+    the reference reaches, and (b) the residual fractional gap is orders
+    of magnitude below the rounding-threshold margins, which
     ``tests/harness.py::decision_margin`` certifies per run.
 
 Padding is *stronger* than the reference's inertness: ``tau_A`` carries
@@ -44,13 +52,16 @@ from __future__ import annotations
 
 import functools
 
-import numpy as np
-
 #: float64 polish-tail length (iterations) of the mixed-precision schedule.
 POLISH_TAIL = 64
 
 #: iterations per Pallas grid step (state stays in VMEM within a block).
 PALLAS_BLOCK = 8
+
+#: the constants ``_fused_step`` reads — the Pallas kernel's inputs.
+STEP_KEYS = ("sizes_r", "oh", "E", "Et", "R", "ddl", "s_u", "T_r", "L_r",
+             "sig_eq", "sig_mem", "sig_route", "sig_lat", "sig_load",
+             "sig_ax", "tau_x", "tau_A", "tau_prec", "tAT", "tAL")
 
 
 def _f(v, dtype):
@@ -59,123 +70,125 @@ def _f(v, dtype):
     return jnp.asarray(v, dtype)
 
 
+def _rows(t, N, H):
+    """``(N, U, H)`` per-(BS, user, exit) tensor → ``(N·(H+1), U)`` row
+    layout, with a zero exit-0 row per base station."""
+    import jax.numpy as jnp
+
+    t = jnp.pad(jnp.swapaxes(t, 1, 2), ((0, 0), (1, 0), (0, 0)))
+    return t.reshape(N * (H + 1), t.shape[-1])
+
+
 def _constants(data, dtype):
-    """Precomputed step-size / operator tensors in the fused (N, H, U)
-    layout, all cast to ``dtype``.  Pure function of the PDHGData pytree;
-    shared verbatim by the scan and Pallas engines."""
+    """Precomputed step-size / operator tensors in the fused row layout,
+    all cast to ``dtype``.  Pure function of the PDHGData pytree; shared
+    verbatim by the scan and Pallas engines."""
     import jax.numpy as jnp
 
     sizes = _f(data.sizes, dtype)                      # (M, H+1)
     onehot_mu = _f(data.onehot_mu, dtype)              # (U, M)
-    R = _f(data.R, dtype)
-    ddl = _f(data.ddl, dtype)
-    s_u = _f(data.s_u, dtype)
+    T = _f(data.T, dtype)                              # (N, U, H)
+    L = _f(data.L, dtype)
     bs_mask = _f(data.bs_mask, dtype)
-    T = jnp.swapaxes(_f(data.T, dtype), 1, 2)          # (N, H, U)
-    L = jnp.swapaxes(_f(data.L, dtype), 1, 2)
-    prec_hu = jnp.swapaxes(_f(data.prec_u, dtype), 0, 1)   # (H, U)
-    N, H, U = T.shape
+    N, U, H = T.shape
     M = sizes.shape[0]
-    NH = N * H
+    P = H + 1
 
     u_mask = onehot_mu.sum(-1)                         # 0.0 on padded users
-    T_t = T.reshape(NH, U).T                           # (U, NH) contiguous
-    L_t = L.reshape(NH, U).T
+    E = jnp.repeat(jnp.eye(N, dtype=dtype), P, axis=1)   # (N, R) BS blocks
+    hmask = (jnp.arange(N * P) % P != 0).astype(dtype)[:, None]  # exit rows
+    sizes_r = jnp.tile(sizes.T, (N, 1))                # (R, M)
+    T_r, L_r = _rows(T, N, H), _rows(L, N, H)          # (R, U)
+    prec_r = _rows(jnp.broadcast_to(_f(data.prec_u, dtype), (N, U, H)),
+                   N, H)
 
     # Pock–Chambolle diagonal step sizes (alpha = 1), exactly the
     # reference's row/column sums
-    sig_eq = jnp.full((N, M), 1.0, dtype) / jnp.maximum(
-        jnp.full((N, M), float(H + 1), dtype), 1e-9)
-    sig_mem = 1.0 / jnp.maximum(jnp.ones((N,), dtype) * sizes.sum(), 1e-9)
+    sig_eq = jnp.full((N, M), 1.0 / (H + 1), dtype)
+    sig_mem = 1.0 / jnp.maximum(jnp.full((N, 1), 1.0, dtype) * sizes.sum(),
+                                1e-9)
     sig_route = 1.0 / jnp.maximum(
-        jnp.ones((U,), dtype) * bs_mask.sum() * H, 1e-9)
-    sig_lat = 1.0 / jnp.maximum(T.sum(axis=(0, 1)), 1e-9)
-    sig_load = 1.0 / jnp.maximum(L.sum(axis=(0, 1)), 1e-9)
-    sig_ax = 0.5  # Python float: weak-typed, exact in both precisions
+        jnp.ones((1, U), dtype) * bs_mask.sum() * H, 1e-9)
+    sig_lat = 1.0 / jnp.maximum(T.sum(axis=(0, 2))[None], 1e-9)
+    sig_load = 1.0 / jnp.maximum(L.sum(axis=(0, 2))[None], 1e-9)
+    sig_ax = 0.5 * hmask                               # exit-0 duals frozen
 
-    cx = jnp.ones((N, M, H + 1), dtype) + sizes[None]
-    cx = cx.at[:, :, 1:].add(onehot_mu.sum(0)[None, :, None])
+    cx = 1.0 + sizes_r + hmask * onehot_mu.sum(0)[None]
     tau_x = 1.0 / jnp.maximum(cx, 1e-9)
-    # row mask (padded BSs) AND column mask (padded users): masked entries
-    # get a zero step, so A stays exactly 0.0 there for the whole solve
-    tau_A = (bs_mask[:, None, None] * u_mask[None, None, :]) \
-        / jnp.maximum(2.0 + T + L, 1e-9)
-    tau_prec = tau_A * prec_hu[None]                   # objective gradient
-    tAT = tau_A * T                                    # folded prox tensors
-    tAL = tau_A * L
+    # exit-0 rows, padded BSs and padded users get a zero step, so A
+    # stays exactly 0.0 there for the whole solve
+    row_mask = jnp.repeat(bs_mask, P)[:, None] * hmask
+    tau_A = (row_mask * u_mask[None]) / jnp.maximum(2.0 + T_r + L_r, 1e-9)
 
-    # bs_mask / u_mask / prec_hu are read only by the diagnostics sampler
-    # (not listed in the Pallas const_keys — _fused_step never touches them)
-    return dict(sizes=sizes, onehot_mu=onehot_mu, R=R, ddl=ddl, s_u=s_u,
-                T=T, L=L, T_t=T_t, L_t=L_t,
+    # bs_mask / u_mask / prec_r are read only by the diagnostics sampler
+    return dict(sizes_r=sizes_r, oh=onehot_mu.T, E=E, Et=E.T,
+                R=_f(data.R, dtype)[:, None],
+                ddl=_f(data.ddl, dtype)[None], s_u=_f(data.s_u, dtype)[None],
+                T_r=T_r, L_r=L_r,
                 sig_eq=sig_eq, sig_mem=sig_mem, sig_route=sig_route,
                 sig_lat=sig_lat, sig_load=sig_load, sig_ax=sig_ax,
-                tau_x=tau_x, tau_A=tau_A, tau_prec=tau_prec,
-                tAT=tAT, tAL=tAL, bs_mask=bs_mask, u_mask=u_mask,
-                prec_hu=prec_hu, dims=(N, M, H, U))
+                tau_x=tau_x, tau_A=tau_A,
+                tau_prec=tau_A * prec_r,               # objective gradient
+                tAT=tau_A * T_r, tAL=tau_A * L_r,      # folded prox tensors
+                bs_mask=bs_mask, u_mask=u_mask, prec_r=prec_r,
+                dims=(N, M, H, U))
+
+
+def _dot(a, b, contract=(1, 0)):
+    """2-D GEMM at full precision: on the TPU the default f32 dot takes
+    reduced-precision passes, which would break the one-hot identities."""
+    import jax
+
+    return jax.lax.dot_general(
+        a, b, (((contract[0],), (contract[1],)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=a.dtype)
 
 
 def _apply_K(c, x, A):
-    """The forward operator K in the fused layout: per-family residuals
-    of (x (N,M,H+1), A (N,H,U))."""
-    import jax
-    import jax.numpy as jnp
-
-    N, M, H, U = c["dims"]
-    y_eq = x.sum(-1) - 1.0                                       # (N, M)
-    y_mem = (x * c["sizes"][None]).sum((-2, -1)) - c["R"]        # (N,)
-    A_t = A.reshape(N * H, U).T                                  # (U, NH)
-    y_route = A_t.sum(-1) - 1.0                                  # (U,)
-    y_lat = (A_t * c["T_t"]).sum(-1) - c["ddl"]
-    y_load = (A_t * c["L_t"]).sum(-1) - c["s_u"]
-    xg = jnp.swapaxes(x[:, :, 1:], 1, 2)                         # (N, H, M)
+    """The forward operator K in the row layout: per-family residuals of
+    (x (R, M), A (R, U))."""
+    y_eq = _dot(c["E"], x) - 1.0                                  # (N, M)
+    y_mem = _dot(c["E"], x * c["sizes_r"]).sum(-1, keepdims=True) \
+        - c["R"]                                                  # (N, 1)
+    y_route = A.sum(0, keepdims=True) - 1.0                       # (1, U)
+    y_lat = (A * c["T_r"]).sum(0, keepdims=True) - c["ddl"]
+    y_load = (A * c["L_r"]).sum(0, keepdims=True) - c["s_u"]
     # one-hot GEMM over M: exactly one term per output, so bit-identical
-    # to the gather xg[:, :, m_u] it replaces — and faster, M is tiny and
-    # the contraction vectorizes where the gather's index plumbing won't
-    xa = jax.lax.dot_general(
-        xg, c["onehot_mu"], (((2,), (1,)), ((), ())),
-        preferred_element_type=x.dtype)                          # (N, H, U)
-    return y_eq, y_mem, y_route, y_lat, y_load, A - xa
+    # to the gather x[n, m_u, h] it replaces
+    return y_eq, y_mem, y_route, y_lat, y_load, A - _dot(x, c["oh"])
 
 
 def _init_state(data, dtype):
-    """The reference's cold start (x = 1/(H+1), A = 0, y = K applied
-    once... the reference initializes y = 0 and we match it exactly:
-    zeros_like of one K application)."""
+    """The reference's cold start: x = 1/(H+1), A = 0, every dual 0."""
     import jax.numpy as jnp
 
     c = _constants(data, dtype)
     N, M, H, U = c["dims"]
-    x = jnp.full((N, M, H + 1), 1.0 / (H + 1), dtype)
-    A = jnp.zeros((N, H, U), dtype)
+    x = jnp.full((N * (H + 1), M), 1.0 / (H + 1), dtype)
+    A = jnp.zeros((N * (H + 1), U), dtype)
     y = tuple(jnp.zeros_like(v) for v in _apply_K(c, x, A))
     return c, (x, A) + y
 
 
 def _fused_step(c, state):
     """One PDHG iteration (prox-primal → over-relax → dual ascent) on the
-    fused state layout.  This is the single source of truth both engines
+    row layout.  This is the single source of truth both engines
     execute — identical expressions, identical float results."""
-    import jax
     import jax.numpy as jnp
 
     x, A, y_eq, y_mem, y_route, y_lat, y_load, y_ax = state
-    dtype = x.dtype
-    N, M, H, U = c["dims"]
 
-    # KT(y) for x, as one broadcast sum + one real GEMM over users
-    gx = y_eq[:, :, None] + y_mem[:, None, None] * c["sizes"][None]
-    gx_sub = jax.lax.dot_general(
-        y_ax, c["onehot_mu"], (((2,), (0,)), ((), ())),
-        preferred_element_type=dtype)                            # (N, H, M)
-    gx = gx.at[:, :, 1:].add(-jnp.swapaxes(gx_sub, 1, 2))
+    # KT(y) for x: the per-BS duals broadcast to their rows, minus the
+    # coupling dual contracted over users (zero on the exit-0 rows)
+    gx = _dot(c["Et"], y_eq) + _dot(c["Et"], y_mem) * c["sizes_r"] \
+        - _dot(y_ax, c["oh"], contract=(1, 1))                    # (R, M)
     x_new = jnp.clip(x - c["tau_x"] * gx, 0.0, 1.0)
     # routing prox with tau_A folded into the operator tensors; tau_prec
     # carries the (negated) objective gradient
     A_new = jnp.clip(
-        A - c["tau_A"] * (y_route[None, None, :] + y_ax)
-        - c["tAT"] * y_lat[None, None, :] - c["tAL"] * y_load[None, None, :]
-        + c["tau_prec"], 0.0, 1.0)
+        A - c["tau_A"] * (y_route + y_ax)
+        - c["tAT"] * y_lat - c["tAL"] * y_load + c["tau_prec"], 0.0, 1.0)
     xb = 2 * x_new - x                                           # over-relax
     Ab = 2 * A_new - A
     k_eq, k_mem, k_route, k_lat, k_load, k_ax = _apply_K(c, xb, Ab)
@@ -198,15 +211,17 @@ def _diag_sample(c, state):
     """(primal residual, dual displacement, objective) of the current
     fused state, cast to float64 — the same masked residual contract as
     the reference tap in ``repro.core.lp._pdhg_kernel``, evaluated in
-    the (N, H, U) layout.  Pure: never perturbs the carried state."""
+    the row layout.  Pure: never perturbs the carried state.  The
+    coupling residual's exit-0 rows are ``-x[n, m_u, 0] <= 0``, so they
+    never raise its max above the true one."""
     import jax.numpy as jnp
 
     f64 = _f64()
     x, A = state[0], state[1]
     y_eq, y_mem, y_route, _, _, y_ax = _apply_K(c, x, A)
-    bs = c["bs_mask"] > 0
-    um = c["u_mask"] > 0
-    r_eq = jnp.max(jnp.where(bs[:, None], jnp.abs(y_eq), 0.0))
+    bs = (c["bs_mask"] > 0)[:, None]
+    um = (c["u_mask"] > 0)[None]
+    r_eq = jnp.max(jnp.where(bs, jnp.abs(y_eq), 0.0))
     r_mem = jnp.max(jnp.where(bs, y_mem, -jnp.inf)) \
         / jnp.maximum(c["R"].max(), 1e-9)
     r_route = jnp.max(jnp.where(um, y_route, -jnp.inf))
@@ -215,7 +230,7 @@ def _diag_sample(c, state):
                     jnp.maximum(r_route, jnp.max(y_ax))), 0.0)
     x2, A2 = _fused_step(c, state)[:2]
     dual = jnp.maximum(jnp.abs(x2 - x).max(), jnp.abs(A2 - A).max())
-    obj = (jnp.asarray(A, f64) * jnp.asarray(c["prec_hu"], f64)[None]).sum()
+    obj = (jnp.asarray(A, f64) * jnp.asarray(c["prec_r"], f64)).sum()
     return jnp.asarray(primal, f64), jnp.asarray(dual, f64), obj
 
 
@@ -229,17 +244,20 @@ def _f64():
 
 
 def _finalize(state, dims):
-    """Fused state → the reference's (x (N,M,H+1), A (N,U,H)) float64."""
+    """Row-layout state → the reference's (x (N,M,H+1), A (N,U,H))
+    float64."""
     import jax.numpy as jnp
 
     N, M, H, U = dims
-    x, A = state[0], state[1]
-    return (jnp.asarray(x, _f64()),
+    x = state[0].reshape(N, H + 1, M)
+    A = state[1].reshape(N, H + 1, U)[:, 1:]
+    return (jnp.swapaxes(jnp.asarray(x, _f64()), 1, 2),
             jnp.swapaxes(jnp.asarray(A, _f64()), 1, 2))
 
 
 # ---------------------------------------------------------------------------
-# engine: lax.scan (the XLA realization; production path off-TPU)
+# engine: lax.scan (the XLA realization; production path off-TPU, and the
+# float64 polish everywhere)
 # ---------------------------------------------------------------------------
 
 def _scan_phase(data, state, iters, dtype):
@@ -268,9 +286,10 @@ def _pallas_phase(data, state, iters, dtype, block=PALLAS_BLOCK,
     kernel invocation per iteration block, zero HBM round-trips inside.
 
     The kernel body executes ``_fused_step`` verbatim; output matches
-    ``_scan_phase`` at the same dtype up to XLA FMA contraction (dtype
-    ulp per step, asserted in interpret mode by
-    tests/test_pdhg_fused.py)."""
+    ``_scan_phase`` at the same dtype up to FMA contraction (dtype ulp
+    per step, asserted in interpret mode by tests/test_pdhg_fused.py).
+    Every operand is 2-D and whole-array, so under ``vmap`` each window
+    is one more (outer) grid step."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -288,11 +307,7 @@ def _pallas_phase(data, state, iters, dtype, block=PALLAS_BLOCK,
     state = _cast_state(state, dtype)
     shapes = [jax.ShapeDtypeStruct(v.shape, v.dtype) for v in state]
     n_state = len(state)
-    # constants the step reads, as kernel inputs (whole-array blocks)
-    const_keys = ("sizes", "onehot_mu", "R", "ddl", "s_u", "T_t", "L_t",
-                  "sig_eq", "sig_mem", "sig_route", "sig_lat",
-                  "sig_load", "tau_x", "tau_A", "tau_prec", "tAT", "tAL")
-    consts = [c[k] for k in const_keys]
+    consts = [c[k] for k in STEP_KEYS]
 
     def run(state, n_steps, n_blk):
         def kernel(*refs):
@@ -300,9 +315,7 @@ def _pallas_phase(data, state, iters, dtype, block=PALLAS_BLOCK,
             out_refs = refs[n_state + len(consts):
                             n_state + len(consts) + n_state]
             scratch = refs[n_state + len(consts) + n_state:]
-            cc = {k: v[...] for k, v in zip(const_keys, in_refs[n_state:])}
-            cc["sig_ax"] = c["sig_ax"]
-            cc["dims"] = c["dims"]
+            cc = {k: v[...] for k, v in zip(STEP_KEYS, in_refs[n_state:])}
 
             j = pl.program_id(0)
 
@@ -322,15 +335,17 @@ def _pallas_phase(data, state, iters, dtype, block=PALLAS_BLOCK,
                 for o, s in zip(out_refs, scratch):
                     o[...] = s[...]
 
+        def whole(j):
+            # int32 block indices: the offline pipeline traces under x64,
+            # and Mosaic takes no int64 index
+            return jnp.int32(0), jnp.int32(0)
+
         return pl.pallas_call(
             kernel,
             grid=(n_blk,),
-            in_specs=[pl.BlockSpec(v.shape, lambda j, sh=v.shape:
-                                   (0,) * len(sh))
+            in_specs=[pl.BlockSpec(v.shape, whole)
                       for v in list(state) + consts],
-            out_specs=[pl.BlockSpec(s.shape, lambda j, sh=s.shape:
-                                    (0,) * len(sh))
-                       for s in shapes],
+            out_specs=[pl.BlockSpec(s.shape, whole) for s in shapes],
             out_shape=shapes,
             scratch_shapes=[_vmem(v.shape, v.dtype) for v in state],
             interpret=interpret,
@@ -361,7 +376,9 @@ def pdhg_fused(data, iters: int, polish: int = POLISH_TAIL,
 
     Runs ``iters - polish`` float32 sweep iterations then ``polish``
     float64 iterations of the same fused step, and returns float64
-    ``(x (N,M,H+1), A (N,U,H))`` in the reference layout.  ``engine``:
+    ``(x (N,M,H+1), A (N,U,H))`` in the reference layout.  ``engine``
+    picks the realization of the float32 sweep (the float64 polish always
+    runs on the scan engine):
 
       * ``"auto"``  — Pallas on TPU, ``lax.scan`` elsewhere (the fast
         realization per platform; both run the identical step);
@@ -398,18 +415,17 @@ def pdhg_fused(data, iters: int, polish: int = POLISH_TAIL,
         _pallas_phase, block=block, interpret=interpret)
 
     f64 = _f64()
+    c64 = _constants(data, f64)
     if not diagnostics:
         if sweep:
             _, state = _init_state(data, jnp.float32)
             state = phase(data, state, sweep, jnp.float32)
         else:
             _, state = _init_state(data, f64)
-        state = phase(data, state, polish, f64)
-        N, M, H, U = _constants(data, f64)["dims"]
-        return _finalize(state, (N, M, H, U))
+        state = _scan_phase(data, state, polish, f64)
+        return _finalize(state, c64["dims"])
 
     stride = max(1, int(diag_stride))
-    c64 = _constants(data, f64)
     samples = []  # (sampled iteration, primal, dual, obj)
     if sweep:
         c32 = _constants(data, jnp.float32)
@@ -426,11 +442,11 @@ def pdhg_fused(data, iters: int, polish: int = POLISH_TAIL,
     x_sw, A_sw = _finalize(state, c64["dims"])
     n2, r2 = divmod(polish, stride)
     for s in range(n2):
-        state = phase(data, state, stride, f64)
+        state = _scan_phase(data, state, stride, f64)
         samples.append((sweep + (s + 1) * stride,) + _diag_sample(c64, state))
     # unconditional, mirroring the diag-off path: a zero-length phase
     # call still applies the f64 cast
-    state = phase(data, state, r2, f64)
+    state = _scan_phase(data, state, r2, f64)
     if r2 or not samples:
         samples.append((iters,) + _diag_sample(c64, state))
     x, A = _finalize(state, c64["dims"])

@@ -147,7 +147,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: pathlib.Path,
 
     mesh = make_production_mesh(multi_pod=multi_pod)
     try:
-        with mesh:
+        with jax.set_mesh(mesh):
             t0 = time.time()
             lowered = lower_cell(arch, shape_name, mesh)
             rec["lower_s"] = round(time.time() - t0, 1)

@@ -40,11 +40,15 @@ def make_host_mesh(*, data: int = 1, model: int = 1):
     n = data * model
     devices = jax.devices()
     if len(devices) < n:
+        platform = devices[0].platform
+        hint = (f"set XLA_FLAGS=--xla_force_host_platform_device_count={n} "
+                "before the first jax import for virtual CPU devices"
+                if platform == "cpu" else
+                f"run on a host with {n} {platform} devices")
         raise RuntimeError(
             f"mesh ({data}, {model}) needs {n} devices, but only "
-            f"{len(devices)} exist — set XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={n} before the first "
-            "jax import (see benchmarks/bench_scale.py), or shrink the mesh")
+            f"{len(devices)} {platform} device(s) exist — {hint}, or "
+            "shrink the mesh")
     dev = np.asarray(devices[:n]).reshape((data, model))
     return jax.sharding.Mesh(dev, ("data", "model"))
 

@@ -52,7 +52,7 @@ def main():
         psh = shd.named(mesh, shd.param_specs(cfg, mesh, shapes["params"]))
         print(f"mesh {mesh.shape}; params sharded FSDPxTP; "
               f"microbatches={cfg.train_microbatches}")
-        with mesh:
+        with jax.set_mesh(mesh):
             _run(cfg, oc, args)
         return
     _run(cfg, oc, args)

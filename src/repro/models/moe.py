@@ -71,7 +71,8 @@ def moe_fwd(cfg: ModelConfig, p, x):
     keep = (pos < C).astype(jnp.float32)
 
     # dispatch (G,g,E,C) one-hot; combine adds gate weights
-    pos_oh = jax.nn.one_hot(pos, C, dtype=jnp.float32) * keep[..., None]
+    pos_oh = jax.nn.one_hot(pos.astype(jnp.int32), C,
+                            dtype=jnp.float32) * keep[..., None]
     disp = jnp.einsum("gske,gskc->gsec", onehot, pos_oh)                 # (G,g,E,C)
     comb = jnp.einsum("gsk,gske,gskc->gsec", gate_vals, onehot, pos_oh)
 

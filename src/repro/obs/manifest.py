@@ -1,11 +1,11 @@
 """Run manifests: provenance JSON written next to every results file.
 
 A manifest answers "what produced this JSON?" without re-running
-anything: git SHA + dirty flag, jax version / backend / devices / x64
-flag (only if jax is already imported — building a manifest never
-triggers device initialization), python/numpy/platform, the argv that
-launched the run, seeds, and the run config with a canonical sha256
-hash so two runs can be compared by a single string.
+anything: git SHA + dirty flag, jax version / backend / device kind /
+devices / x64 flag (only if jax is already imported — building a
+manifest never triggers device initialization), python/numpy/platform,
+the argv that launched the run, seeds, and the run config with a
+canonical sha256 hash so two runs can be compared by a single string.
 """
 from __future__ import annotations
 
@@ -46,6 +46,7 @@ def _jax_info() -> dict:
         return {"imported": True,
                 "version": jax.__version__,
                 "backend": devices[0].platform if devices else None,
+                "device_kind": devices[0].device_kind if devices else None,
                 "device_count": len(devices),
                 "devices": [str(d) for d in devices],
                 "x64": bool(jax.config.jax_enable_x64)}

@@ -11,11 +11,12 @@ it through three composable layers:
      padding waste;
   2. **mesh partitioning**: each bucket's batch axis is partitioned
      across a ``("data", "model")`` host-device mesh with
-     ``jax.experimental.shard_map`` (``launch/mesh.py`` plumbing;
-     ``XLA_FLAGS=--xla_force_host_platform_device_count=K`` puts K
-     virtual devices on one host) — the grid axes (variants × seeds ×
-     policies / windows / workload families) all live on the stacked
-     batch axis, so "data" is the only mesh axis the executor shards;
+     ``jax.shard_map`` (``launch/mesh.py`` plumbing: the chips of one
+     TPU host, or K virtual CPU devices under
+     ``XLA_FLAGS=--xla_force_host_platform_device_count=K``) — the grid
+     axes (variants × seeds × policies / windows / workload families)
+     all live on the stacked batch axis, so "data" is the only mesh axis
+     the executor shards;
   3. **chunked streaming**: the batch is dispatched in fixed-size chunks
      whose device buffers are donated (``donate_argnums``), so peak live
      memory is O(chunk), not O(grid), as grids grow to thousands of
@@ -150,12 +151,11 @@ def _compile(kind, mesh, n_args, make_inner, *statics):
 
         fn = make_inner()
         if mesh is not None:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
             s = P("data")
-            fn = shard_map(fn, mesh=mesh, in_specs=(s,) * n_args,
-                           out_specs=s, check_rep=False)
+            fn = jax.shard_map(fn, mesh=mesh, in_specs=(s,) * n_args,
+                               out_specs=s, check_vma=False)
         _COMPILED[key] = OT.register_jit(
             f"scale:{key}", jax.jit(fn, donate_argnums=tuple(range(n_args))))
     return _COMPILED[key]
@@ -201,7 +201,6 @@ def _run_chunks(spec: GridSpec, mesh, fn, args, B: int, stats: dict,
     strictly after the dispatch, so it cannot perturb results; when
     diagnostics are off it is never called."""
     import jax
-    from jax.experimental import enable_x64
 
     make = args if callable(args) else \
         (lambda take: tuple(_take_rows(a, take) for a in args))
@@ -231,7 +230,7 @@ def _run_chunks(spec: GridSpec, mesh, fn, args, B: int, stats: dict,
                             bucket=str(bucket_key), chunk=ci,
                             n_chunks=n_chunks, batch=int(len(take)),
                             pad_rows=pad_rows, in_bytes=in_bytes) as sp:
-            with enable_x64():
+            with jax.enable_x64(True):
                 if sharding is not None:
                     chunk_args = tuple(jax.device_put(a, sharding)
                                        for a in chunk_args)
@@ -247,6 +246,10 @@ def _run_chunks(spec: GridSpec, mesh, fn, args, B: int, stats: dict,
                         "ignore",
                         message="Some donated buffers were not usable")
                     out = fn(*chunk_args)
+                # which devices the chunk ran on, read from the arrays
+                sp.attrs["devices"] = sorted(
+                    {d.id for a in jax.tree.leaves((chunk_args, out))
+                     for d in a.sharding.device_set})
                 out = jax.tree.map(np.asarray, out)
             if spec.diagnostics:
                 from repro.obs.metrics import memory_snapshot
@@ -310,9 +313,8 @@ def _element_key(seed, index):
     """The ``per_element`` RNG scheme: one PRNG key per original grid
     index, independent of bucketing/chunking/sharding by construction."""
     import jax
-    from jax.experimental import enable_x64
 
-    with enable_x64():
+    with jax.enable_x64(True):
         return jax.random.fold_in(jax.random.PRNGKey(seed), int(index))
 
 

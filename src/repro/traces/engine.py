@@ -31,9 +31,9 @@ without ever materializing a ``(T, U)`` or even full ``(T, N, M)``
 tensor.
 
 Numerics: the engine mirrors ``OnlineSim`` op-for-op (same stable sort
-orders, same thresholds) and runs in float64 (``jax.experimental
-.enable_x64``), so per-slot QoE and final cache state match the NumPy
-engine to ~1e-12 — asserted in ``tests/test_traces.py``.
+orders, same thresholds) and runs in float64 (``jax.enable_x64``), so
+per-slot QoE and final cache state match the NumPy engine to ~1e-12 —
+asserted in ``tests/test_traces.py``.
 """
 from __future__ import annotations
 
@@ -488,10 +488,10 @@ def run_scan(params: OnlineParams, counts, stream: DecisionStream,
     inert: same compiled step math, extra emissions only), and, with
     ``record_states``, the per-slot serving cache states under
     ``"states"`` (the serving bridge's input)."""
-    from jax.experimental import enable_x64
+    import jax
 
     st0 = init_state(params, dT_past)
-    with enable_x64():
+    with jax.enable_x64(True):
         stF, qoe, hits, diag, rec = _compiled(
             bool(diagnostics), bool(record_states))(
             params, st0, np.asarray(counts, np.float64),
@@ -531,13 +531,13 @@ def run_workload(params: OnlineParams, workload, stream: DecisionStream,
     one-shot scan; at most two chunk lengths (full + tail) ever compile.
     Returns the ``run_scan`` summary dict.
     """
-    from jax.experimental import enable_x64
+    import jax
 
     st = init_state(params, dT_past)
     fn = _compiled(bool(diagnostics), bool(record_states))
     pid = _policy_id(algo)
     qoes, hitss, diags, recs, total = [], [], [], [], 0.0
-    with enable_x64():
+    with jax.enable_x64(True):
         for t0, t1, counts in workload.iter_chunks(chunk_slots):
             counts = np.asarray(counts, np.float64)
             total += float(counts.sum())

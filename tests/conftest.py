@@ -4,7 +4,7 @@ import sys
 # tests see the single real CPU device (the 512-device override is ONLY for
 # launch/dryrun.py, which sets XLA_FLAGS itself before importing jax)
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-# make _hypothesis_fallback importable from test modules
+# make the shared harness importable from test modules
 sys.path.insert(0, os.path.dirname(__file__))
 
 import numpy as np  # noqa: E402

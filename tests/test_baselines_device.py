@@ -14,8 +14,8 @@ from repro.mec.scenario import MECConfig, stack_instances
 
 
 def _x64():
-    from jax.experimental import enable_x64
-    return enable_x64()
+    import jax
+    return jax.enable_x64(True)
 
 
 def _dev(fn, inst, *args):

@@ -3,10 +3,7 @@ statistical tests), repair feasibility — including hypothesis property tests
 over random JDCR instances."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # pragma: no cover - single-example fallback
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import lp as LP
 from repro.core.cocar import cocar_window

@@ -174,19 +174,21 @@ def test_moe_gmm_interpret_explicit():
 @pytest.mark.slow_compile
 def test_pdhg_fused_interpret_explicit():
     """The fused PDHG kernel honours interpret=True and agrees with the
-    scan engine (same _fused_step source) on a small instance."""
+    scan engine (same _fused_step source) on a small instance.  The
+    kernel body is dtype-generic, so interpret mode runs it in f64 —
+    which pins the engines to f64 FMA noise."""
     from harness import make_instance
     from repro.core import lp as LP
-    from repro.kernels.pdhg_fused import pdhg_fused
-    from jax.experimental import enable_x64
+    from repro.kernels import pdhg_fused as PF
     inst = make_instance(seed=6, n_users=16, n_bs=2)
-    with enable_x64():
+    with jax.enable_x64(True):
         data = jax.tree.map(jnp.asarray, LP.pdhg_data(inst))
-        xs, As = pdhg_fused(data, 24, polish=24, engine="scan")
-        xp, Ap = pdhg_fused(data, 24, polish=24, engine="pallas",
-                            block=8, interpret=True)
-    np.testing.assert_allclose(np.asarray(xp), np.asarray(xs), atol=1e-12)
-    np.testing.assert_allclose(np.asarray(Ap), np.asarray(As), atol=1e-12)
+        _, st = PF._init_state(data, jnp.float64)
+        ss = PF._scan_phase(data, st, 24, jnp.float64)
+        sp = PF._pallas_phase(data, st, 24, jnp.float64, block=8,
+                              interpret=True)
+    for a, b in zip(sp, ss):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-12)
 
 
 def test_flash_matches_model_attention():

@@ -20,10 +20,7 @@ import sys
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # pragma: no cover - single-example fallback
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.obs import (COUNT_EDGES, UNIT_EDGES, Counter, EventLog, Gauge,
                        Histogram, MetricsRegistry, memory_snapshot,

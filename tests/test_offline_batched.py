@@ -16,11 +16,11 @@ from repro.mec.scenario import MECConfig, stack_instances
 def both_repairs(inst, x, A):
     """Run the NumPy reference and the device kernel on the same rounded
     input; assert they make identical decisions, then return them."""
-    from jax.experimental import enable_x64
+    import jax
 
     xh, Ah = repair(inst, np.array(x), np.array(A))
     data = LP.pdhg_data(inst)
-    with enable_x64():
+    with jax.enable_x64(True):
         xd, Ad = repair_device(data, np.array(x), np.array(A))
     xd, Ad = np.asarray(xd), np.asarray(Ad)
     assert np.array_equal(xh, xd), (xh, xd)
@@ -37,8 +37,8 @@ def both_repairs(inst, x, A):
 # ---------------------------------------------------------------------------
 
 def test_tree_sum_matches_numpy_and_is_padding_invariant():
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     rng = np.random.default_rng(0)
     for n in (1, 2, 3, 7, 17, 64, 150):
@@ -49,14 +49,14 @@ def test_tree_sum_matches_numpy_and_is_padding_invariant():
         padded = np.concatenate([v, np.zeros((5, 37))], axis=-1)
         assert np.array_equal(tree_sum(padded, -1), ref)
         # the jnp path folds the same adds -> bit-identical to numpy
-        with enable_x64():
+        with jax.enable_x64(True):
             dev = np.asarray(tree_sum(jnp.asarray(v), -1))
         assert np.array_equal(dev, ref)
 
 
 def test_round_from_uniforms_np_jnp_identical():
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     inst = make_instance(n_users=30)
     x_f, A_f, _ = LP.solve_lp_scipy(inst)
@@ -67,7 +67,7 @@ def test_round_from_uniforms_np_jnp_identical():
                                           inst.H)
     xh, Ah = round_from_uniforms(np.asarray(x_f), np.asarray(A_f), onehot,
                                  u_cat, u_phi)
-    with enable_x64():
+    with jax.enable_x64(True):
         xd, Ad = round_from_uniforms(jnp.asarray(x_f), jnp.asarray(A_f),
                                      jnp.asarray(onehot),
                                      jnp.asarray(u_cat),
@@ -214,7 +214,7 @@ def test_best_of_trial_argmax_agreement():
 def test_check_feasible_device_on_pipeline_outputs():
     """The jnp feasibility residuals, evaluated on the padded pipeline
     outputs, must report every repaired window as feasible."""
-    from jax.experimental import enable_x64
+    import jax
 
     from repro.core.jdcr import check_feasible_device
 
@@ -226,7 +226,7 @@ def test_check_feasible_device_on_pipeline_outputs():
     for i in range(len(stacked)):
         data_i = type(stacked.data)(*(v[i] for v in stacked.data))
         for s in range(2):
-            with enable_x64():
+            with jax.enable_x64(True):
                 res = check_feasible_device(data_i, dev["x"][i, s],
                                             dev["A"][i, s])
             for k, v in res.items():

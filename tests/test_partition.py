@@ -2,10 +2,7 @@
 submodel sizes are monotone, Δ-chains telescope, catalogs are consistent."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # pragma: no cover - single-example fallback
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro import configs
 from repro.models import partition

@@ -133,7 +133,7 @@ def test_sharded_train_step_tiny_mesh():
     shapes = jax.eval_shape(lambda: M.init(cfg, jax.random.key(0)))
     pspec = shd.param_specs(cfg, mesh, shapes)
     psh = shd.named(mesh, pspec)
-    with mesh:
+    with jax.set_mesh(mesh):
         state = init_train_state(cfg, jax.random.key(0))
         state = {"params": jax.device_put(state["params"], psh),
                  "opt": state["opt"]}

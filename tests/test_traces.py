@@ -235,9 +235,9 @@ def _both_engines(sim):
 
 
 def _routine_jax(params, st):
-    from jax.experimental import enable_x64
+    import jax
 
-    with enable_x64():
+    with jax.enable_x64(True):
         out = E._routine_update(params, st)
         return E.OnlineState(*(np.asarray(x) for x in out))
 

@@ -5,10 +5,7 @@ demand — only the summation order can differ)."""
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # pragma: no cover - single-example fallback
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.online import OnlineConfig, OnlineSim, run_online
 from repro.mec.scenario import MECConfig
