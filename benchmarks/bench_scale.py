@@ -150,12 +150,12 @@ def bench_throughput(n_variants=None, n_users=40, n_seeds=2, best_of=8,
 
     # warm both compile caches, then measure steady state
     run_grid(GridSpec(**kw, backend="vmap"))
-    with TRACER.span("bench:one_device", variants=n_variants) as sp:
+    with TRACER.span("scale:one_device", variants=n_variants) as sp:
         one_dev = run_grid(GridSpec(**kw, backend="vmap"))
     t_vmap = sp.seconds
 
     run_grid(GridSpec(**kw, backend="sharded", chunk_size=chunk))
-    with TRACER.span("bench:sharded", variants=n_variants,
+    with TRACER.span("scale:sharded", variants=n_variants,
                      chunk=chunk) as sp:
         shd = run_grid(GridSpec(**kw, backend="sharded", chunk_size=chunk))
     t_shard = sp.seconds

@@ -347,9 +347,9 @@ def phase_sharded(seed: int, n_chips: int, base=None,
         return cocar_grid(insts, backend="sharded", devices=n_chips, **kw)
 
     out, setup_s = _timed(sharded)
-    n_spans = len(TRACER.spans)
+    t_warm = time.perf_counter()
     _, warm_s = _timed(sharded)
-    devices = sorted({d for sp in TRACER.spans[n_spans:]
+    devices = sorted({d for sp in TRACER.since(t_warm)
                       if sp.name == "chunk" for d in sp.attrs["devices"]})
     def one_chip():
         return cocar_grid(insts, backend="device", **kw)

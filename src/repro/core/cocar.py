@@ -83,20 +83,29 @@ def _pipeline_kernel(data, u_cat, u_phi, iters, n_seeds,
     import jax
     import jax.numpy as jnp
 
-    lp_out = LP._lp_solve_kernel(data, iters, backend,
-                                 diagnostics=diagnostics)
+    # the stages are named scopes, so that their operations carry the
+    # stage's name in the compiled program and the profiler's trace
+    with jax.named_scope("lp"):
+        lp_out = LP._lp_solve_kernel(data, iters, backend,
+                                     diagnostics=diagnostics)
     x_f, A_f = lp_out[0], lp_out[1]
-    x_r, A_r = round_from_uniforms(x_f, A_f, data.onehot_mu, u_cat, u_phi)
-    x_p, A_p = jax.vmap(repair_device, in_axes=(None, 0, 0))(data, x_r, A_r)
-    objs = jax.vmap(lambda a: objective_sel(data.prec_u, a))(A_p)
-    T = objs.shape[0] // n_seeds
-    objs = objs.reshape(n_seeds, T)
-    best_t = jnp.argmax(objs, axis=1)                       # (S,)
-    idx = jnp.arange(n_seeds) * T + best_t
-    x_b, A_b = x_p[idx], A_p[idx]                           # (S, ...)
-    met = jax.vmap(lambda xx, aa: MET.window_metrics_device(data, xx, aa))(
-        x_b, A_b)
-    lp_obj = jnp.einsum("nuh,uh->", A_f, data.prec_u)
+    with jax.named_scope("round"):
+        x_r, A_r = round_from_uniforms(x_f, A_f, data.onehot_mu, u_cat,
+                                       u_phi)
+    with jax.named_scope("repair"):
+        x_p, A_p = jax.vmap(repair_device, in_axes=(None, 0, 0))(data, x_r,
+                                                                 A_r)
+    with jax.named_scope("select"):
+        objs = jax.vmap(lambda a: objective_sel(data.prec_u, a))(A_p)
+        T = objs.shape[0] // n_seeds
+        objs = objs.reshape(n_seeds, T)
+        best_t = jnp.argmax(objs, axis=1)                   # (S,)
+        idx = jnp.arange(n_seeds) * T + best_t
+        x_b, A_b = x_p[idx], A_p[idx]                       # (S, ...)
+    with jax.named_scope("metrics"):
+        met = jax.vmap(
+            lambda xx, aa: MET.window_metrics_device(data, xx, aa))(x_b, A_b)
+        lp_obj = jnp.einsum("nuh,uh->", A_f, data.prec_u)
     out = {"x_frac": x_f, "A_frac": A_f, "x": x_b, "A": A_b,
            "trial_objs": objs, "best_t": best_t, "metrics": met,
            "lp_obj": lp_obj}

@@ -36,9 +36,13 @@ PDHG residual/convergence columns on offline rows, per-slot cache
 telemetry summaries on online rows — and provably decision-inert
 (``--no-diag`` compiles them out).  Every results JSON gets a sibling
 ``*.manifest.json`` (git SHA, jax/device info, seeds, config hash) and
-``*.trace.jsonl`` / ``*.trace.chrome.json`` span exports; render them
-with ``scripts/report.py results/sweep``.  ``--smoke`` runs a 2-window
-offline CI grid into ``results/sweep/ci/``.
+a ``*.trace.jsonl`` export of the program's spans (``repro.obs.tracing``:
+wall and thread CPU time per sweep, grid run, chunk and executor stage);
+render them with ``scripts/report.py results/sweep``.  The same spans are
+named host events in a ``jax.profiler`` trace, beside the device's
+operations: run under ``jax.profiler.trace(<dir>)`` and open the trace in
+TensorBoard or Perfetto.  ``--smoke`` runs a 2-window offline CI grid
+into ``results/sweep/ci/``.
 """
 from __future__ import annotations
 
@@ -354,7 +358,6 @@ def main(online: bool = False, backend: str = "device", n_seeds: int = 1,
                                diagnostics=diagnostics, smoke=smoke),
                    seeds={"seed": 0, "n_seeds": n_seeds})
     TRACER.export_jsonl(path.with_name(path.stem + ".trace.jsonl"))
-    TRACER.export_chrome(path.with_name(path.stem + ".trace.chrome.json"))
     if registry is not None:
         registry.export_prometheus(
             path.with_name(path.stem + ".metrics.prom"))

@@ -5,9 +5,11 @@ events, and jit-safe solver/engine diagnostics summaries.
 Five parts (docs/algorithms.md Sec. 11 and 14):
 
   * :mod:`repro.obs.tracing` — ``Span``/``Tracer`` built on the
-    monotonic ``time.perf_counter``, with JSONL + chrome://tracing
-    export and a registry of jitted entry points whose compile-cache
-    sizes turn into per-span retrace counts;
+    monotonic ``time.perf_counter``, with thread CPU time per span, the
+    spans named in any ``jax.profiler`` trace, ``Tracer.since`` and
+    JSONL export as the in-process reads, and a registry of jitted
+    entry points whose compile-cache sizes turn into per-span retrace
+    counts;
   * :mod:`repro.obs.manifest` — ``run_manifest``/``write_manifest``:
     git SHA, jax/device info, x64 flags, seeds, and a config hash next
     to every emitted results file;

@@ -223,7 +223,8 @@ def _run_chunks(spec: GridSpec, mesh, fn, args, B: int, stats: dict,
         if chunk == B and not callable(args):
             chunk_args = args                     # whole grid, one chunk:
         else:                                     # no identity row-copy
-            chunk_args = make(take)
+            with OT.TRACER.span("grid.prepare", count_retraces=False):
+                chunk_args = make(take)
         in_bytes = sum(_nbytes(a) for a in chunk_args)
         pad_rows = int(chunk - (min(start + chunk, B) - start))
         with OT.TRACER.span("chunk", kind=spec.kind,
@@ -231,13 +232,11 @@ def _run_chunks(spec: GridSpec, mesh, fn, args, B: int, stats: dict,
                             n_chunks=n_chunks, batch=int(len(take)),
                             pad_rows=pad_rows, in_bytes=in_bytes) as sp:
             with jax.enable_x64(True):
-                if sharding is not None:
+                with OT.TRACER.span("grid.put", count_retraces=False):
                     chunk_args = tuple(jax.device_put(a, sharding)
                                        for a in chunk_args)
-                else:
-                    chunk_args = tuple(jax.device_put(a)
-                                       for a in chunk_args)
-                with warnings.catch_warnings():
+                with OT.TRACER.span("grid.dispatch", count_retraces=False), \
+                        warnings.catch_warnings():
                     # donation is best-effort: only inputs whose shape/
                     # layout matches an output can be reused (the online
                     # state is; most static tensors are not) — the
@@ -250,7 +249,12 @@ def _run_chunks(spec: GridSpec, mesh, fn, args, B: int, stats: dict,
                 sp.attrs["devices"] = sorted(
                     {d.id for a in jax.tree.leaves((chunk_args, out))
                      for d in a.sharding.device_set})
-                out = jax.tree.map(np.asarray, out)
+                # the device's work the host does not overlap, then the
+                # copy of the finished outputs to the host
+                with OT.TRACER.span("grid.wait", count_retraces=False):
+                    jax.block_until_ready(out)
+                with OT.TRACER.span("grid.fetch", count_retraces=False):
+                    out = jax.tree.map(np.asarray, out)
             if spec.diagnostics:
                 from repro.obs.metrics import memory_snapshot
 
@@ -338,41 +342,45 @@ def _run_offline(spec: GridSpec, mesh, stats):
     M, H = insts[0].M, insts[0].H
     N_g = max(i.N for i in insts)
     U_g = max(i.U for i in insts)
-    plan = plan_buckets([(i.N, i.U) for i in insts], spec.max_buckets,
-                        round_users_to=spec.round_users_to)
-    stats["plan"] = plan.key
-    S, T = int(spec.n_seeds), max(int(spec.best_of), 1)
-    if spec.rng == "stacked":
-        # the same tensors offline_uniforms draws for the max-padded stack
-        u_cat, u_phi = draw_rounding_uniforms(spec.seed, S * T, N_g, M,
-                                              U_g, H, batch=B)
+    with OT.TRACER.span("grid.prepare", count_retraces=False):
+        plan = plan_buckets([(i.N, i.U) for i in insts], spec.max_buckets,
+                            round_users_to=spec.round_users_to)
+        stats["plan"] = plan.key
+        S, T = int(spec.n_seeds), max(int(spec.best_of), 1)
+        if spec.rng == "stacked":
+            # the tensors offline_uniforms draws for the max-padded stack
+            u_cat, u_phi = draw_rounding_uniforms(spec.seed, S * T, N_g, M,
+                                                  U_g, H, batch=B)
 
     results = [None] * B
     for bucket in plan.buckets:
         idx = np.asarray(bucket.indices)
         Nb, Ub = bucket.n_bs, bucket.n_users
-        stacked = stack_instances([insts[i] for i in idx],
-                                  pad_to=(Nb, Ub))
-        if spec.rng == "stacked":
-            args = (stacked.data,
-                    _fit_axes(u_cat[idx], (2, Nb)),
-                    _fit_axes(u_phi[idx], (2, Nb), (3, Ub)))
-        else:
-            def args(take, idx=idx, data=stacked.data, Nb=Nb, Ub=Ub):
-                ucs, ups = zip(*(
-                    draw_rounding_uniforms(_element_key(spec.seed, idx[j]),
-                                           S * T, N_g, M, U_g, H)
-                    for j in take))
-                return (_take_rows(data, take),
-                        np.stack([_fit_axes(u, (1, Nb)) for u in ucs]),
-                        np.stack([_fit_axes(u, (1, Nb), (2, Ub))
-                                  for u in ups]))
+        with OT.TRACER.span("grid.prepare", count_retraces=False):
+            stacked = stack_instances([insts[i] for i in idx],
+                                      pad_to=(Nb, Ub))
+            if spec.rng == "stacked":
+                args = (stacked.data,
+                        _fit_axes(u_cat[idx], (2, Nb)),
+                        _fit_axes(u_phi[idx], (2, Nb), (3, Ub)))
+            else:
+                def args(take, idx=idx, data=stacked.data, Nb=Nb, Ub=Ub):
+                    ucs, ups = zip(*(
+                        draw_rounding_uniforms(
+                            _element_key(spec.seed, idx[j]), S * T, N_g, M,
+                            U_g, H)
+                        for j in take))
+                    return (_take_rows(data, take),
+                            np.stack([_fit_axes(u, (1, Nb)) for u in ucs]),
+                            np.stack([_fit_axes(u, (1, Nb), (2, Ub))
+                                      for u in ups]))
         fn = _compile("offline", mesh, 3, _offline_inner(spec),
                       int(spec.pdhg_iters), S, spec.lp_backend,
                       bool(spec.diagnostics))
         out = _run_chunks(spec, mesh, fn, args,
                           len(idx), stats, bucket_key=bucket.key)
-        per = CC._unstack_device(stacked, out, S)
+        with OT.TRACER.span("grid.unstack", count_retraces=False):
+            per = CC._unstack_device(stacked, out, S)
         for j, i in enumerate(idx):
             results[int(i)] = per[j]
     return results
@@ -387,10 +395,14 @@ def _offline_inner(spec: GridSpec):
         iters, n_seeds = int(spec.pdhg_iters), int(spec.n_seeds)
         lp_backend = spec.lp_backend
         diagnostics = bool(spec.diagnostics)
-        return jax.vmap(
-            lambda d, uc, up: _pipeline_kernel(d, uc, up, iters, n_seeds,
-                                               backend=lp_backend,
-                                               diagnostics=diagnostics))
+        def cocar_offline_pipeline(d, uc, up):
+            return _pipeline_kernel(d, uc, up, iters, n_seeds,
+                                    backend=lp_backend,
+                                    diagnostics=diagnostics)
+
+        # a named function, so that the compiled executable is named
+        # after it in the profiler's trace
+        return jax.vmap(cocar_offline_pipeline)
     return make
 
 

@@ -16,6 +16,7 @@ import importlib.util
 import json
 import pathlib
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -293,7 +294,7 @@ def test_executor_watermarks_decision_inert():
     kw = dict(kind="offline", insts=insts, seed=0, n_seeds=1, best_of=2,
               pdhg_iters=150, backend="vmap")
     off = run_grid(GridSpec(**kw))
-    n0 = len(OT.TRACER.spans)
+    t0 = time.perf_counter()
     on = run_grid(GridSpec(**kw, diagnostics=True))
     assert_same_offline(off.results, on.results)
     for k in ("peak_host_rss_kb", "peak_host_maxrss_kb",
@@ -302,7 +303,7 @@ def test_executor_watermarks_decision_inert():
         assert k not in off.stats                # skipped when off
     assert on.stats["peak_host_rss_kb"] > 0
     # every chunk span of the diagnostics run carries the watermarks
-    chunks = [s for s in OT.TRACER.spans[n0:] if s.name == "chunk"]
+    chunks = [s for s in OT.TRACER.since(t0) if s.name == "chunk"]
     assert chunks
     for s in chunks:
         assert "host_rss_kb" in s.attrs
