@@ -191,6 +191,20 @@ def _pdhg_kernel(data: PDHGData, iters: int, diagnostics: bool = False,
     tau_j = 1/sum_i |K_ij|, sigma_i = 1/sum_j |K_ij|.  Duals: the one-hot
     equality (N,M) is free, every inequality dual is projected to >= 0.
 
+    Working layout: every per-(BS, user, exit) tensor — A, its
+    extrapolation, the coupling dual, ``tau_A``, T, L and the objective
+    ``prec_u`` — is held as (N, H, U), users minor, transposed once
+    outside the loop; A goes back to (N, U, H) on return.  On the TPU the
+    minor axis maps to the 128 vreg lanes, so (N, U, H) would fill 3 of
+    them.  The loop body has no ``dot_general``: the chip has no float64
+    matrix unit, and XLA emulates an f64 dot as nested loops over f32
+    and bf16 pieces.  The coupling ``x_a[n,h,u] = x[n, m_u, 1+h]`` selects
+    ``x[n, m, 1+h]`` where user u wants model m and sums over the M
+    models, which is exact: every term but one is 0.0 (all of them for
+    padded users, whose ``onehot_mu`` row is 0).  Its adjoint is a masked
+    lane sum over users per model, and the latency, load and memory rows
+    are multiply-then-reduce.
+
     With ``diagnostics=True`` the same iteration runs as nested scans over
     ``diag_stride``-sized segments (bit-identical composition — the scan
     body is unchanged and segment boundaries only read the carry) and the
@@ -210,40 +224,44 @@ def _pdhg_kernel(data: PDHGData, iters: int, diagnostics: bool = False,
     import jax
     import jax.numpy as jnp
 
-    sizes, prec_u, T, L, onehot_mu, R, ddl, s_u, bs_mask = (
-        data.sizes, data.prec_u, data.T, data.L, data.onehot_mu,
-        data.R, data.ddl, data.s_u, data.bs_mask)
-    N, U, H = T.shape
+    sizes, onehot_mu, R, ddl, s_u, bs_mask = (
+        data.sizes, data.onehot_mu, data.R, data.ddl, data.s_u,
+        data.bs_mask)
+    N, U, H = data.T.shape
     M = sizes.shape[0]
+    # (N, H, U) working layout, users on the lanes (see the docstring)
+    T = jnp.swapaxes(data.T, 1, 2)
+    L = jnp.swapaxes(data.L, 1, 2)
+    prec_u = data.prec_u.T                                          # (H,U)
+    wants = onehot_mu.T > 0                                         # (M,U)
 
     def K(x, A):
         y_eq = x.sum(-1) - 1.0                                      # (N,M)
-        y_mem = jnp.einsum("nmh,mh->n", x, sizes) - R               # (N,)
-        y_route = A.sum(axis=(0, 2)) - 1.0                          # (U,)
-        y_lat = jnp.einsum("nuh,nuh->u", A, T) - ddl                # (U,)
-        y_load = jnp.einsum("nuh,nuh->u", A, L) - s_u               # (U,)
-        xa = jnp.einsum("nmh,um->nuh", x[:, :, 1:], onehot_mu)      # (N,U,H)
-        y_ax = A - xa                                               # (N,U,H)
+        y_mem = (x * sizes).sum((1, 2)) - R                         # (N,)
+        y_route = A.sum((0, 1)) - 1.0                               # (U,)
+        y_lat = (A * T).sum((0, 1)) - ddl                           # (U,)
+        y_load = (A * L).sum((0, 1)) - s_u                          # (U,)
+        xa = jnp.where(wants[:, None], x[:, :, 1:, None],
+                       0.0).sum(1)                                  # (N,H,U)
+        y_ax = A - xa                                               # (N,H,U)
         return y_eq, y_mem, y_route, y_lat, y_load, y_ax
 
     def KT(y):
         y_eq, y_mem, y_route, y_lat, y_load, y_ax = y
-        gx = jnp.zeros((N, M, H + 1))
-        gx += y_eq[:, :, None]
-        gx += y_mem[:, None, None] * sizes[None]
-        gx_sub = -jnp.einsum("nuh,um->nmh", y_ax, onehot_mu)        # (N,M,H)
-        gx = gx.at[:, :, 1:].add(gx_sub)
-        gA = y_route[None, :, None] + y_ax \
-            + y_lat[None, :, None] * T + y_load[None, :, None] * L
+        gx_sub = -jnp.where(wants[:, None], y_ax[:, None],
+                            0.0).sum(-1)                            # (N,M,H)
+        gx = y_eq[:, :, None] + y_mem[:, None, None] * sizes[None] \
+            + jnp.pad(gx_sub, ((0, 0), (0, 0), (1, 0)))
+        gA = y_route + y_ax + y_lat * T + y_load * L
         return gx, gA
 
     # row sums (per dual)
     r_eq = jnp.full((N, M), float(H + 1))
     r_mem = jnp.ones((N,)) * sizes.sum()
     r_route = jnp.ones((U,)) * bs_mask.sum() * H     # only real BSs route
-    r_lat = T.sum(axis=(0, 2))
-    r_load = L.sum(axis=(0, 2))
-    r_ax = jnp.full((N, U, H), 2.0)
+    r_lat = T.sum((0, 1))
+    r_load = L.sum((0, 1))
+    r_ax = jnp.full((N, H, U), 2.0)
     sig = tuple(1.0 / jnp.maximum(r, 1e-9)
                 for r in (r_eq, r_mem, r_route, r_lat, r_load, r_ax))
     # column sums (per primal)
@@ -251,7 +269,7 @@ def _pdhg_kernel(data: PDHGData, iters: int, diagnostics: bool = False,
     cx += sizes[None]                                               # mem
     users_of_m = onehot_mu.sum(0)                                   # (M,)
     cx = cx.at[:, :, 1:].add(users_of_m[None, :, None])             # A<=x
-    cA = jnp.ones((N, U, H)) + T + L + 1.0                          # route+lat+load+ax
+    cA = jnp.ones((N, H, U)) + T + L + 1.0                          # route+lat+load+ax
     tau_x = 1.0 / jnp.maximum(cx, 1e-9)
     # masked rows get a zero step: A starts at 0 there and stays exactly 0,
     # so padded base stations never couple into the real rows' duals
@@ -262,7 +280,7 @@ def _pdhg_kernel(data: PDHGData, iters: int, diagnostics: bool = False,
         return (y_eq,) + tuple(jnp.maximum(v, 0.0) for v in ineq)
 
     x = jnp.full((N, M, H + 1), 1.0 / (H + 1))
-    A = jnp.zeros((N, U, H))
+    A = jnp.zeros((N, H, U))
     y = tuple(jnp.zeros_like(v) for v in K(x, A))
 
     def body(carry, _):
@@ -270,7 +288,7 @@ def _pdhg_kernel(data: PDHGData, iters: int, diagnostics: bool = False,
         gx, gA = KT(y)
         # gradient of -objective wrt A is -prec
         x_new = jnp.clip(x - tau_x * gx, 0.0, 1.0)
-        A_new = jnp.clip(A - tau_A * (gA - prec_u[None]), 0.0, 1.0)
+        A_new = jnp.clip(A - tau_A * (gA - prec_u), 0.0, 1.0)
         xb = 2 * x_new - x
         Ab = 2 * A_new - A
         Ky = K(xb, Ab)
@@ -280,7 +298,7 @@ def _pdhg_kernel(data: PDHGData, iters: int, diagnostics: bool = False,
 
     if not diagnostics:
         (x, A, y), _ = jax.lax.scan(body, (x, A, y), None, length=iters)
-        return x, A
+        return x, jnp.swapaxes(A, 1, 2)
 
     bs = bs_mask > 0                                            # (N,)
     um = onehot_mu.sum(-1) > 0                                  # (U,)
@@ -297,7 +315,7 @@ def _pdhg_kernel(data: PDHGData, iters: int, diagnostics: bool = False,
                         jnp.maximum(r_route, jnp.max(y_ax))), 0.0)
         (x2, A2, _), _ = body(carry, None)
         dual = jnp.maximum(jnp.abs(x2 - x).max(), jnp.abs(A2 - A).max())
-        obj = jnp.einsum("nuh,uh->", A, prec_u)
+        obj = (A * prec_u).sum()
         return primal, dual, obj
 
     n_seg, rem = divmod(int(iters), int(diag_stride))
@@ -323,7 +341,7 @@ def _pdhg_kernel(data: PDHGData, iters: int, diagnostics: bool = False,
     diag = {"iters": jnp.asarray(sampled, dtype=jnp.int32),
             "primal_res": pr, "dual_res": dr, "obj": ob}
     x, A, _ = carry
-    return x, A, diag
+    return x, jnp.swapaxes(A, 1, 2), diag
 
 
 #: LP solver backends: "reference" is the plain f64 kernel above;

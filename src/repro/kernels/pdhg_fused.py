@@ -1,9 +1,9 @@
 """Fused PDHG inner loop for the P1-LR window solver.
 
 ``repro.core.lp._pdhg_kernel`` — the bit-compared reference — materializes
-the full primal/dual state through HBM every iteration: four dense one-hot
-einsums, separate strided reductions per dual family, and a dozen
-elementwise passes.  This module is the fused production path behind
+the full primal/dual state through HBM every iteration: selects and masked
+sums for the one-hot coupling, separate reductions per dual family, and a
+dozen elementwise passes.  This module is the fused production path behind
 ``solve_lp_pdhg(..., backend="pallas")``:
 
   * **one step, restructured** (``_fused_step``) on a 2-D *row layout*:

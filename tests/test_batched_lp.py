@@ -95,6 +95,30 @@ def test_batched_matches_scalar_pdhg():
     np.testing.assert_allclose(res_b.A[0], res_s.A, atol=1e-5)
 
 
+@pytest.mark.parametrize("diagnostics", [False, True])
+def test_reference_kernel_lowers_without_dot_general(diagnostics):
+    """The reference LP kernel, vmapped over a window axis as
+    ``_pipeline_kernel`` runs it at Sec. VII-A sizes (N=5, U=600, M=8,
+    H=3), lowers with no ``dot_general``: the TPU has no float64 matrix
+    unit, so XLA would emulate each one as nested loops over f32 and
+    bf16 pieces inside every PDHG iteration."""
+    import functools
+
+    import jax
+
+    with jax.enable_x64(True):
+        data = LP.pdhg_data(make_instance(n_users=600, n_bs=5, n_models=8))
+        spec = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct((1,) + np.shape(a), np.float64),
+            data)
+        fn = jax.vmap(functools.partial(
+            LP._lp_solve_kernel, iters=4000, backend="reference",
+            diagnostics=diagnostics))
+        text = jax.jit(fn).lower(spec).as_text()
+    assert "while" in text
+    assert "dot_general" not in text
+
+
 def test_round_solution_batch_shapes_and_marginals():
     """Batched trials are iid draws of Alg. 1: caching rows stay one-hot
     and the empirical E[objective] over trials matches the LP objective

@@ -52,16 +52,31 @@ def _data(inst):
 # kernel layer
 # ---------------------------------------------------------------------------
 
-def test_fused_f64_matches_reference_closely():
+@pytest.mark.parametrize("shape", [
+    # (seed, users, BSs, models, iterations, padded BSs)
+    (2, 60, 3, 4, 400, 0),
+    (0, 600, 5, 8, 4000, 0),            # Sec. VII-A, the benchmark's size
+    (1, 200, 3, 8, 1000, 2),
+], ids=["small", "sec7a", "padded_bs"])
+def test_fused_f64_matches_reference_closely(shape):
     """With polish == iters the fused path is the reference algorithm
-    with reordered ops — pure f64, gap at accumulated-roundoff scale."""
+    with reordered ops — pure f64, gap at accumulated-roundoff scale.
+    Padded base stations hold exactly 0 routing mass in both."""
+    seed, n_users, n_bs, n_models, iters, pad_bs = shape
     with _x64():
-        inst = make_instance(seed=2, n_users=60)
+        inst = make_instance(seed=seed, n_users=n_users, n_bs=n_bs,
+                             n_models=n_models)
         data = _data(inst)
-        x_r, A_r = LP._pdhg_kernel(data, 400)
-        x_f, A_f = PF.pdhg_fused(data, 400, polish=400, engine="scan")
-        assert float(np.abs(np.asarray(x_f) - np.asarray(x_r)).max()) < 1e-10
-        assert float(np.abs(np.asarray(A_f) - np.asarray(A_r)).max()) < 1e-10
+        if pad_bs:
+            stacked = stack_instances([inst], pad_to=(n_bs + pad_bs,
+                                                      n_users))
+            data = type(stacked.data)(*(v[0] for v in stacked.data))
+        x_r, A_r = (np.asarray(v) for v in LP._pdhg_kernel(data, iters))
+        x_f, A_f = (np.asarray(v) for v in
+                    PF.pdhg_fused(data, iters, polish=iters, engine="scan"))
+    assert float(np.abs(x_f - x_r).max()) < 1e-10
+    assert float(np.abs(A_f - A_r).max()) < 1e-10
+    assert (A_r[n_bs:] == 0.0).all() and (A_f[n_bs:] == 0.0).all()
 
 
 def test_mixed_precision_gap_small_and_finite():
