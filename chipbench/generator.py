@@ -1,10 +1,12 @@
 """The one traffic generator.  A mix is a data file under ``traffic/``;
 it names the driver that feeds it to the program, and the driver draws
-its requests with the class below, which reads the mix's parameters.  Everything is drawn from the seed it is given, and
-every seed gives the same sizes, so that a seed changes what is asked
-and not how much.
+its requests with a class below, which reads the mix's parameters:
+``ZipfWindows`` for observation windows of the control plane,
+``PoissonArrivals`` for requests served as they arrive.  Everything is
+drawn from the seed it is given, and every seed gives the same sizes, so
+that a seed changes what is asked and not how much.
 
-The arithmetic is the benchmark's own copy of the program's seeded
+``ZipfWindows`` is the benchmark's own copy of the program's seeded
 traffic (``Scenario.draw_requests``, ``zipf_popularity``), so that a
 change to the program cannot change the traffic it is judged on.
 """
@@ -45,3 +47,35 @@ class ZipfWindows:
         s_u = self.rng.uniform(0.0, self.window_s, size=self.U)
         return m_u, home, s_u
 
+
+class PoissonArrivals:
+    """Open-loop requests over a window of ``seconds``: arrivals at the
+    mix's ``rate_per_s``, each a prompt of ``prompt_tokens`` ids drawn
+    uniformly from ``[1, vocab)`` and a home pod drawn uniformly.
+
+    The gaps between arrivals are the ``n = round(rate * seconds)``
+    midpoint quantiles of the exponential distribution of that rate,
+    scaled so that the last arrival falls half a mean gap before the
+    window's end, in an order drawn from the mix's ``schedule_seed``.  So
+    every run offers the same Poisson-like schedule, and its seed draws
+    the prompts and homes: a seed changes what is asked, not when.  An
+    order drawn from the run's seed would change how much the window
+    queues: the 90th percentile of the serving cell's reply time then
+    spreads by a third over six seeds on the chip."""
+
+    def __init__(self, mix: dict, vocab: int, n_homes: int, seconds: float,
+                 seed: int):
+        rate = float(mix["rate_per_s"])
+        n = max(1, int(round(rate * seconds)))
+        q = (np.arange(n) + 0.5) / n
+        gaps = -np.log1p(-q)
+        gaps *= seconds * (n - 0.5) / n / gaps.sum()
+        order = np.random.default_rng(int(mix["schedule_seed"])).permutation(n)
+        self.at = np.cumsum(gaps[order])
+        rng = np.random.default_rng(seed)
+        self.prompts = rng.integers(1, vocab, size=(n, int(
+            mix["prompt_tokens"])), dtype=np.int32)
+        self.homes = rng.integers(0, n_homes, size=n)
+
+    def __len__(self):
+        return len(self.at)

@@ -11,6 +11,15 @@ SMALL = {
     "sec7a-offline": {"files": ("sec7a", "zipf-windows"),
                       "config": {"n_users": 200, "pdhg_iters": 300},
                       "traffic": {"check_windows": 3}},
+    "edge4-qwen-poisson": {
+        "files": ("edge4-qwen1.5-0.5b", "poisson-serve"),
+        "config": {"hidden_size": 64, "intermediate_size": 128,
+                   "num_attention_heads": 4, "num_key_value_heads": 4,
+                   "num_hidden_layers": 4, "vocab_size": 500,
+                   "exit_layers": [2, 3, 4], "torch_dtype": "float32"},
+        "traffic": {"prompt_tokens": 16, "new_tokens": 4,
+                    "rate_per_s": 16.0, "batch_sizes": [1, 2],
+                    "check_batches": 3}},
 }
 
 
