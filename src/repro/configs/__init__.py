@@ -9,7 +9,7 @@ import importlib
 ARCH_IDS = [
     "zamba2-1.2b", "stablelm-12b", "chatglm3-6b", "qwen1.5-0.5b",
     "qwen3-14b", "pixtral-12b", "mixtral-8x22b", "mixtral-8x7b",
-    "whisper-small", "xlstm-125m",
+    "whisper-small", "xlstm-125m", "moonlight-16b-a3b",
 ]
 
 _MOD = {a: a.replace("-", "_").replace(".", "_") for a in ARCH_IDS}

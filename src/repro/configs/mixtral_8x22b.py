@@ -11,14 +11,14 @@ CONFIG = ModelConfig(
     name="mixtral-8x22b", family="moe",
     n_layers=56, d_model=6144, n_heads=48, n_kv_heads=8, d_ff=16384,
     vocab_size=32768, head_dim=128, sliding_window=4096,
-    n_experts=8, top_k=2, capacity_factor=1.25, moe_group_size=2048,
+    n_experts=8, top_k=2,
 )
 
 SMOKE = ModelConfig(
     name="mixtral22-smoke", family="moe",
     n_layers=4, d_model=64, n_heads=4, n_kv_heads=2, d_ff=128,
     vocab_size=512, head_dim=16, sliding_window=16,
-    n_experts=4, top_k=2, capacity_factor=2.0, moe_group_size=32,
+    n_experts=4, top_k=2,
     exit_layers=(2, 3, 4), dtype="float32", param_dtype="float32", remat=False,
     vocab_pad_multiple=16,
 )

@@ -1,13 +1,15 @@
 """qwen1.5-0.5b — dense MHA with QKV bias [hf:Qwen/Qwen1.5-0.5B].
 
-24L, d_model 1024, 16 heads (kv=16), d_ff 2816, vocab 151936.
+24L, d_model 1024, 16 heads (kv=16), d_ff 2816, vocab 151936, RoPE theta
+1e6, RMSNorm eps 1e-6.
 """
 from repro.models.config import ModelConfig
 
 CONFIG = ModelConfig(
     name="qwen1.5-0.5b", family="dense",
     n_layers=24, d_model=1024, n_heads=16, n_kv_heads=16, d_ff=2816,
-    vocab_size=151936, head_dim=64, qkv_bias=True,
+    vocab_size=151936, head_dim=64, qkv_bias=True, rope_theta=1e6,
+    norm_eps=1e-6,
 )
 
 SMOKE = ModelConfig(

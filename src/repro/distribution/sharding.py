@@ -217,7 +217,10 @@ def cache_specs(cfg: ModelConfig, mesh: Mesh, batch: int, plan=None):
     kv = P(None, bd, None, M if kdiv else None, None)
     out = []
     for seg in plan.segments:
-        if seg.kind in ("dense", "moe"):
+        if seg.kind in ("dense", "moe") and cfg.attn_kind == "mla":
+            lat = P(None, bd, None, None)
+            out.append({"c": lat, "kpe": lat})
+        elif seg.kind in ("dense", "moe"):
             out.append({"k": kv, "v": kv})
         elif seg.kind == "shared_attn":
             skv = P(bd, None, M if kdiv else None, None)

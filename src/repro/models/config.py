@@ -41,11 +41,23 @@ class ModelConfig:
     qk_norm: bool = False
     qkv_bias: bool = False
     sliding_window: int = 0          # 0 -> full attention
+    attn_kind: str = "gqa"           # gqa | mla (DeepSeek-V3 latent attention)
+    # --- MLA (attn_kind "mla"): q without LoRA, k/v from a normed latent ---
+    kv_lora_rank: int = 0            # latent width of c_kv (cached)
+    qk_nope_dim: int = 0             # per-head q/k width without RoPE
+    qk_rope_dim: int = 0             # per-head q width with RoPE; k's is shared
+    v_head_dim: int = 0
     # --- MoE ----------------------------------------------------------------
-    n_experts: int = 0
+    n_experts: int = 0               # routed experts the router scores
     top_k: int = 0
-    capacity_factor: float = 1.25
-    moe_group_size: int = 2048       # GShard dispatch group length
+    moe_d_ff: int = 0                # routed/shared expert width; 0 -> d_ff
+    n_shared_experts: int = 0        # always-on experts, one SwiGLU of
+                                     # n_shared_experts * moe_d_ff
+    first_dense_layers: int = 0      # leading dense layers before the MoE
+    router: str = "softmax"          # softmax | sigmoid_bias (V3 noaux_tc)
+    routed_scale: float = 1.0        # routed weights' factor after top-k
+    experts_held: Tuple[int, ...] = ()  # (first, count) of the routed
+                                     # experts this chip holds; () -> all
     # --- SSM (mamba2) -------------------------------------------------------
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -97,6 +109,19 @@ class ModelConfig:
             n = len(self.exit_layers)
             w = tuple(0.3 for _ in range(n - 1)) + (1.0,)
             object.__setattr__(self, "exit_loss_weights", w)
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """(first, count) of the routed experts whose weights live here."""
+        return tuple(self.experts_held) or (0, self.n_experts)
+
+    @property
+    def qk_head_dim(self) -> int:   # MLA: q/k width per head
+        return self.qk_nope_dim + self.qk_rope_dim
 
     @property
     def padded_vocab(self) -> int:
@@ -157,7 +182,8 @@ def _backbone_kinds(cfg: ModelConfig):
     if cfg.family in ("dense", "vlm"):
         kinds = [("dense", True)] * cfg.n_layers
     elif cfg.family == "moe":
-        kinds = [("moe", True)] * cfg.n_layers
+        k = cfg.first_dense_layers
+        kinds = [("dense", True)] * k + [("moe", True)] * (cfg.n_layers - k)
     elif cfg.family == "hybrid_mamba":
         for i in range(cfg.n_layers):
             kinds.append(("mamba", True))

@@ -9,7 +9,8 @@ an f32 buffer).  This is simultaneously
     (prefill_32k / train_4k), and
   * the pure-jnp oracle structure mirrored by ``kernels/flash_attention``.
 
-Layout: q (B, S, H, E); k, v (B, T, K, E) with H = G·K (GQA).  The mask is
+Layout: q (B, S, H, E); k (B, T, K, E), v (B, T, K, Ev) with H = G·K
+(GQA); v's width may differ from q's and k's (latent attention).  The mask is
 positional: causal with optional sliding window, with ``q_offset`` giving the
 absolute position of query row 0.
 """
@@ -35,12 +36,12 @@ def _mask(qpos, kpos, causal, window):
 def _fwd(q, k, v, causal, window, q_offset, bq, bk):
     B, S, H, E = q.shape
     T, K = k.shape[1], k.shape[2]
-    G = H // K
+    G, Ev = H // K, v.shape[-1]
     scale = E ** -0.5
     nq, nk = S // bq, T // bk
     qb = q.reshape(B, nq, bq, K, G, E)
     kb = k.reshape(B, nk, bk, K, E)
-    vb = v.reshape(B, nk, bk, K, E)
+    vb = v.reshape(B, nk, bk, K, Ev)
 
     def q_step(_, qi_idx):
         qi, iq = qi_idx
@@ -62,7 +63,7 @@ def _fwd(q, k, v, causal, window, q_offset, bq, bk):
 
         m0 = jnp.full((B, K, G, bq), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, K, G, bq), jnp.float32)
-        a0 = jnp.zeros((B, K, G, bq, E), jnp.float32)
+        a0 = jnp.zeros((B, K, G, bq, Ev), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(
             kv_step, (m0, l0, a0),
             (kb.transpose(1, 0, 2, 3, 4), vb.transpose(1, 0, 2, 3, 4),
@@ -73,22 +74,22 @@ def _fwd(q, k, v, causal, window, q_offset, bq, bk):
 
     _, (ob, lseb) = jax.lax.scan(
         q_step, None, (qb.transpose(1, 0, 2, 3, 4, 5), jnp.arange(nq)))
-    # ob: (nq, B, K, G, bq, E) -> (B, S, H, E)
-    out = ob.transpose(1, 0, 4, 2, 3, 5).reshape(B, S, H, E)
+    # ob: (nq, B, K, G, bq, Ev) -> (B, S, H, Ev)
+    out = ob.transpose(1, 0, 4, 2, 3, 5).reshape(B, S, H, Ev)
     return out, lseb   # lse kept in block layout (nq,B,K,G,bq) for the bwd
 
 
 def _bwd_impl(q, k, v, out, lse, dout, causal, window, q_offset, bq, bk):
     B, S, H, E = q.shape
     T, K = k.shape[1], k.shape[2]
-    G = H // K
+    G, Ev = H // K, v.shape[-1]
     scale = E ** -0.5
     nq, nk = S // bq, T // bk
     qb = q.reshape(B, nq, bq, K, G, E).transpose(1, 0, 2, 3, 4, 5)
-    dob = dout.reshape(B, nq, bq, K, G, E).transpose(1, 0, 2, 3, 4, 5)
-    ob = out.reshape(B, nq, bq, K, G, E).transpose(1, 0, 2, 3, 4, 5)
+    dob = dout.reshape(B, nq, bq, K, G, Ev).transpose(1, 0, 2, 3, 4, 5)
+    ob = out.reshape(B, nq, bq, K, G, Ev).transpose(1, 0, 2, 3, 4, 5)
     kb = k.reshape(B, nk, bk, K, E).transpose(1, 0, 2, 3, 4)
-    vb = v.reshape(B, nk, bk, K, E).transpose(1, 0, 2, 3, 4)
+    vb = v.reshape(B, nk, bk, K, Ev).transpose(1, 0, 2, 3, 4)
     # D_i = rowsum(dout * out)
     Db = jnp.sum(dob.astype(jnp.float32) * ob.astype(jnp.float32), axis=-1)
     # Db: (nq, B, bq, K, G); lse: (nq, B, K, G, bq)
@@ -114,9 +115,10 @@ def _bwd_impl(q, k, v, out, lse, dout, causal, window, q_offset, bq, bk):
                                      p.astype(doi.dtype), doi).astype(jnp.float32)
             return (dk_j, dv_j), dqi
 
-        z = jnp.zeros((B, bk, K, E), jnp.float32)
         (dk_j, dv_j), dqs = jax.lax.scan(
-            q_step, (z, z), (qb, dob, lse, Db, jnp.arange(nq)))
+            q_step, (jnp.zeros((B, bk, K, E), jnp.float32),
+                     jnp.zeros((B, bk, K, Ev), jnp.float32)),
+            (qb, dob, lse, Db, jnp.arange(nq)))
         dq_acc = dq_acc + dqs.astype(jnp.float32)
         return dq_acc, (dk_j, dv_j)
 
@@ -124,14 +126,14 @@ def _bwd_impl(q, k, v, out, lse, dout, causal, window, q_offset, bq, bk):
     dq, (dk, dv) = jax.lax.scan(kv_step, dq0, (kb, vb, jnp.arange(nk)))
     dq = dq.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, H, E).astype(q.dtype)
     dk = dk.transpose(1, 0, 2, 3, 4).reshape(B, T, K, E).astype(k.dtype)
-    dv = dv.transpose(1, 0, 2, 3, 4).reshape(B, T, K, E).astype(v.dtype)
+    dv = dv.transpose(1, 0, 2, 3, 4).reshape(B, T, K, Ev).astype(v.dtype)
     return dq, dk, dv
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal=True, window=0, q_offset=0,
                     block_q=512, block_k=1024):
-    """q: (B,S,H,E); k,v: (B,T,K,E) -> (B,S,H,E)."""
+    """q: (B,S,H,E); k: (B,T,K,E), v: (B,T,K,Ev) -> (B,S,H,Ev)."""
     out, _ = _fwd(q, k, v, causal, window, q_offset,
                   min(block_q, q.shape[1]), min(block_k, k.shape[1]))
     return out

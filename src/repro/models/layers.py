@@ -154,7 +154,8 @@ def _qkv(cfg, p, x, n_heads, n_kv, positions, use_rope=True):
 
 
 def gqa_attend(q, k, v, mask):
-    """q: (B,S,H,E), k/v: (B,T,K,E), mask: (S,T) or (B,S,T) additive f32."""
+    """q: (B,S,H,E), k: (B,T,K,E), v: (B,T,K,Ev), mask: (S,T) or (B,S,T)
+    additive f32 -> (B, S, H*Ev)."""
     B, S, H, E = q.shape
     T, K = k.shape[1], k.shape[2]
     G = H // K
@@ -165,7 +166,7 @@ def gqa_attend(q, k, v, mask):
     scores = scores + m[:, None, None, :, :]
     w = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bkgst,btke->bskge", w, v)
-    return out.reshape(B, S, H * E)
+    return out.reshape(B, S, H * v.shape[-1])
 
 
 def causal_mask(S, T=None, window=0, offset=0):

@@ -129,8 +129,10 @@ def cache_init(cfg: ModelConfig, B: int, max_len: int, plan: Plan = None):
 
 
 def prefill(cfg: ModelConfig, params, batch, cache, exit_idx: int = -1,
-            plan: Plan = None):
-    """Returns (last-position logits (B, V), updated cache)."""
+            plan: Plan = None, with_picks: bool = False):
+    """Returns (last-position logits (B, V), updated cache); with
+    ``with_picks`` also the routed experts' picks of every MoE segment
+    run, each (layers, B * S, top_k)."""
     plan = plan or build_plan(cfg)
     exit_idx = exit_idx % cfg.n_exits
     last_seg = plan.exit_after[exit_idx]
@@ -141,19 +143,22 @@ def prefill(cfg: ModelConfig, params, batch, cache, exit_idx: int = -1,
     if plan.has_encoder:
         enc_out = run_encoder(cfg, params, batch["frames"])
 
-    new_cache = list(cache)
+    new_cache, picks = list(cache), []
     for seg in plan.segments[: last_seg + 1]:
         sp = params["segments"][seg.index]
-        h, new_cache[seg.index] = T.seg_prefill(
-            cfg, seg, sp, params.get("shared"), h, positions,
-            cache[seg.index], enc_out=enc_out)
+        out = T.seg_prefill(cfg, seg, sp, params.get("shared"), h, positions,
+                            cache[seg.index], enc_out=enc_out,
+                            with_picks=with_picks and seg.kind == "moe")
+        h, new_cache[seg.index] = out[:2]
+        picks += out[2:]
     logits = exit_head_fwd(cfg, params["exits"][exit_idx], h[:, -1:, :])
-    return logits[:, 0, :], new_cache
+    return (logits[:, 0, :], new_cache) + ((picks,) if with_picks else ())
 
 
 def decode(cfg: ModelConfig, params, tokens, pos, cache, exit_idx: int = -1,
-           plan: Plan = None):
-    """One decode step. tokens: (B, 1) int32, pos: scalar int32."""
+           plan: Plan = None, with_picks: bool = False):
+    """One decode step. tokens: (B, 1) int32, pos: scalar int32;
+    ``with_picks`` as in :func:`prefill`."""
     plan = plan or build_plan(cfg)
     exit_idx = exit_idx % cfg.n_exits
     last_seg = plan.exit_after[exit_idx]
@@ -161,10 +166,13 @@ def decode(cfg: ModelConfig, params, tokens, pos, cache, exit_idx: int = -1,
     if cfg.family == "encdec":
         h = h + sinusoidal(jnp.asarray(pos)[None], cfg.d_model)[None].astype(h.dtype)
 
-    new_cache = list(cache)
+    new_cache, picks = list(cache), []
     for seg in plan.segments[: last_seg + 1]:
         sp = params["segments"][seg.index]
-        h, new_cache[seg.index] = T.seg_decode(
-            cfg, seg, sp, params.get("shared"), h, pos, cache[seg.index])
+        out = T.seg_decode(cfg, seg, sp, params.get("shared"), h, pos,
+                           cache[seg.index],
+                           with_picks=with_picks and seg.kind == "moe")
+        h, new_cache[seg.index] = out[:2]
+        picks += out[2:]
     logits = exit_head_fwd(cfg, params["exits"][exit_idx], h)
-    return logits[:, 0, :], new_cache
+    return (logits[:, 0, :], new_cache) + ((picks,) if with_picks else ())

@@ -1,11 +1,27 @@
-"""Mixture-of-Experts layer (Mixtral-style top-2), GShard einsum dispatch.
+"""Mixture-of-Experts layer: a router over every routed expert, the part of
+the result that this chip's experts give, and always-on shared experts.
 
-TPU-native formulation: tokens are reshaped into groups of ``moe_group_size``;
-within each group a capacity-bounded one-hot dispatch tensor routes tokens to
-experts via einsum (no scatter/gather), which shards cleanly under GSPMD:
-the group axis follows the batch ("data") sharding and each expert's hidden
-dim shards over "model".  HLO FLOPs ≈ capacity_factor × active-expert FLOPs,
-so the roofline's MODEL_FLOPS/HLO_FLOPs ratio stays honest.
+The router scores all ``n_experts`` routed experts of the published model,
+in float32, and picks ``top_k`` per token:
+
+* ``softmax`` (Mixtral): weights are the softmax's values at the picks;
+* ``sigmoid_bias`` (DeepSeek-V3 ``noaux_tc`` with one group): scores are
+  ``sigmoid(logits)``, the picks are the top-k of ``scores + bias`` (the
+  correction bias steers selection only), and the weights are the scores
+  at the picks.
+
+Then the weights are divided by their sum over the picks and multiplied
+by ``routed_scale``.  All of this is computed at the router's full width,
+as every chip of an expert-parallel deployment computes it.
+
+The layer holds the routed experts ``experts_held`` = (first, count) and
+computes only their contribution: the (token, pick) pairs that land on a
+held expert are sorted by expert and run through one grouped product per
+matrix (``jax.lax.ragged_dot``).  Every pair is computed: no token is
+dropped at any batch or length.  Pairs routed elsewhere get nothing here;
+the chips that hold those experts (absent on one chip) would add theirs.
+The shared experts, one SwiGLU of ``n_shared_experts * moe_d_ff``, are
+added once for every token.
 """
 from __future__ import annotations
 
@@ -13,79 +29,92 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.config import ModelConfig
-from repro.models.layers import pdtype
+from repro.models.layers import ffn_fwd, pdtype
 
 
 def moe_init(key, cfg: ModelConfig):
-    D, F, E = cfg.d_model, cfg.d_ff, cfg.n_experts
+    D, F, E = cfg.d_model, cfg.expert_d_ff, cfg.n_experts
+    n = cfg.held_experts[1]
     k0, k1, k2, k3 = jax.random.split(key, 4)
-    return {
+    p = {
         "router": (jax.random.normal(k0, (D, E)) * D ** -0.5).astype(jnp.float32),
-        "w1": (jax.random.normal(k1, (E, D, F)) * D ** -0.5).astype(pdtype(cfg)),
-        "w3": (jax.random.normal(k2, (E, D, F)) * D ** -0.5).astype(pdtype(cfg)),
-        "w2": (jax.random.normal(k3, (E, F, D)) * F ** -0.5).astype(pdtype(cfg)),
+        "w1": (jax.random.normal(k1, (n, D, F)) * D ** -0.5).astype(pdtype(cfg)),
+        "w3": (jax.random.normal(k2, (n, D, F)) * D ** -0.5).astype(pdtype(cfg)),
+        "w2": (jax.random.normal(k3, (n, F, D)) * F ** -0.5).astype(pdtype(cfg)),
     }
+    if cfg.router == "sigmoid_bias":
+        p["bias"] = jnp.zeros((E,), jnp.float32)
+    if cfg.n_shared_experts:
+        Fs = cfg.n_shared_experts * F
+        s1, s2, s3 = jax.random.split(jax.random.fold_in(key, 1), 3)
+        p["shared"] = {
+            "w1": (jax.random.normal(s1, (D, Fs)) * D ** -0.5).astype(pdtype(cfg)),
+            "w3": (jax.random.normal(s2, (D, Fs)) * D ** -0.5).astype(pdtype(cfg)),
+            "w2": (jax.random.normal(s3, (Fs, D)) * Fs ** -0.5).astype(pdtype(cfg)),
+        }
+    return p
 
 
-def _capacity(cfg: ModelConfig, group: int) -> int:
-    c = int(cfg.capacity_factor * group * cfg.top_k / cfg.n_experts)
-    return max(cfg.top_k, (c + 3) // 4 * 4)
+def route(cfg: ModelConfig, p, xf):
+    """xf (T, D) -> (scores (T, E) f32, picks (T, k) int32, weights (T, k)
+    f32), over every routed expert."""
+    # float32 at full precision: the TPU's default would round the router
+    # to bfloat16, and a pick near a tie would then differ from the f32
+    # gate the published models compute
+    logits = jnp.dot(xf.astype(jnp.float32), p["router"],
+                     precision=jax.lax.Precision.HIGHEST)
+    if cfg.router == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+        select = scores
+    elif cfg.router == "sigmoid_bias":
+        scores = jax.nn.sigmoid(logits)
+        select = scores + p["bias"]
+    else:
+        raise ValueError(f"unknown router {cfg.router!r}")
+    _, idx = jax.lax.top_k(select, cfg.top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return scores, idx, w * cfg.routed_scale
+
+
+def held_experts_fwd(cfg: ModelConfig, p, xf, idx, w):
+    """The held experts' part of the routed output for xf (T, D): every
+    (token, pick) pair on a held expert, grouped by expert."""
+    T, D = xf.shape
+    k = idx.shape[-1]
+    first, n = cfg.held_experts
+    local = idx.reshape(-1) - first
+    held = (local >= 0) & (local < n)
+    eid = jnp.where(held, local, n)               # the rest sort after
+    order = jnp.argsort(eid, stable=True)
+    sizes = jnp.zeros((n + 1,), jnp.int32).at[eid].add(1)[:n]
+    tok = order // k
+    xs = xf[tok]
+    dt = xf.dtype
+    h = jax.lax.ragged_dot(xs, p["w1"].astype(dt), sizes)
+    g = jax.lax.ragged_dot(xs, p["w3"].astype(dt), sizes)
+    y = jax.lax.ragged_dot(jax.nn.silu(h) * g, p["w2"].astype(dt), sizes)
+    # the grouped product leaves the rows past its groups undefined (any
+    # value, NaN too, may be there): select the held pairs, never scale
+    out = jnp.where(held[order][:, None],
+                    y.astype(jnp.float32) * w.reshape(-1)[order][:, None],
+                    0.0)
+    return jnp.zeros((T, D), jnp.float32).at[tok].add(out).astype(dt)
 
 
 def moe_fwd(cfg: ModelConfig, p, x):
     """x: (B, S, D) -> (out (B, S, D), aux_loss scalar f32)."""
     B, S, D = x.shape
-    E, k = cfg.n_experts, cfg.top_k
-    T = B * S
-    # one group of all tokens at decode (S==1): per-token groups waste
-    # capacity slots (C >= top_k each); groups never cross batch rows when
-    # S % g == 0, so train/prefill reshapes stay local
-    g = min(cfg.moe_group_size, T)
-    xf = x.reshape(T, D)
-    valid = None
-    if T % g:
-        pad = g - T % g
-        xf = jnp.pad(xf, ((0, pad), (0, 0)))
-        valid = jnp.arange(T + pad) < T       # pads get no expert assignment
-        T = T + pad
-    G = T // g
-    C = _capacity(cfg, g)
-
-    xg = xf.reshape(G, g, D)
-    logits = (xg.astype(jnp.float32) @ p["router"]).astype(jnp.float32)  # (G,g,E)
-    probs = jax.nn.softmax(logits, axis=-1)
-
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)                        # (G,g,k)
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
-
-    # one-hot expert assignment per slot: (G, g, k, E)
-    onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)
-    if valid is not None:
-        onehot = onehot * valid.reshape(G, g)[:, :, None, None]
-    # position of each (token, slot) within its expert queue, slot-major so
-    # first-choice assignments win capacity over second choices.
-    flat = onehot.transpose(0, 2, 1, 3).reshape(G, k * g, E)
-    pos_in_expert = jnp.cumsum(flat, axis=1) - flat                      # (G,kg,E)
-    pos_in_expert = pos_in_expert.reshape(G, k, g, E).transpose(0, 2, 1, 3)
-    pos = jnp.sum(pos_in_expert * onehot, axis=-1)                       # (G,g,k)
-    keep = (pos < C).astype(jnp.float32)
-
-    # dispatch (G,g,E,C) one-hot; combine adds gate weights
-    pos_oh = jax.nn.one_hot(pos.astype(jnp.int32), C,
-                            dtype=jnp.float32) * keep[..., None]
-    disp = jnp.einsum("gske,gskc->gsec", onehot, pos_oh)                 # (G,g,E,C)
-    comb = jnp.einsum("gsk,gske,gskc->gsec", gate_vals, onehot, pos_oh)
-
-    xin = jnp.einsum("gsec,gsd->gecd", disp.astype(x.dtype), xg)         # (G,E,C,D)
-    h = jnp.einsum("gecd,edf->gecf", xin, p["w1"].astype(x.dtype))
-    h = jax.nn.silu(h) * jnp.einsum("gecd,edf->gecf", xin, p["w3"].astype(x.dtype))
-    hout = jnp.einsum("gecf,efd->gecd", h, p["w2"].astype(x.dtype))
-    out = jnp.einsum("gsec,gecd->gsd", comb.astype(x.dtype), hout)
-
-    # Switch-style load-balancing auxiliary loss
-    me = jnp.mean(probs, axis=(0, 1))                                    # (E,)
-    ce = jnp.mean(onehot[..., 0, :] if k == 1 else jnp.max(onehot, 2), axis=(0, 1))
-    aux = E * jnp.sum(me * ce)
-
-    out = out.reshape(T, D)[:B * S]
+    xf = x.reshape(B * S, D)
+    scores, idx, w = route(cfg, p, xf)
+    out = held_experts_fwd(cfg, p, xf, idx, w)
+    if "shared" in p:
+        out = out + ffn_fwd(cfg, p["shared"], xf)
+    aux = jnp.float32(0.0)
+    if cfg.router == "softmax":
+        # Switch-style load balance: mean router probability times the
+        # share of tokens that pick each expert
+        me = jnp.mean(scores, axis=0)
+        ce = jnp.mean(jnp.max(jax.nn.one_hot(idx, cfg.n_experts), 1), 0)
+        aux = cfg.n_experts * jnp.sum(me * ce)
     return out.reshape(B, S, D), aux

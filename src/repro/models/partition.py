@@ -80,19 +80,36 @@ def delta_segments(cfg: ModelConfig, params, i: int, j: int, plan: Plan = None):
 # analytic FLOPs (forward, per token) — feeds c_h and roofline MODEL_FLOPS
 # ---------------------------------------------------------------------------
 
+def _mla_flops(cfg: ModelConfig, ctx: int) -> float:
+    """MLA per token in the absorbed form that decode runs: the q, latent
+    and output projections, q_nope taken into the latent and the output
+    out of it, and scores and the weighted sum over ``ctx`` latents."""
+    D, H, R = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    Dn, Dr, Dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    proj = 2 * D * H * (Dn + Dr) + 2 * D * (R + Dr) + 2 * H * Dv * D
+    absorb = 2 * H * Dn * R + 2 * H * R * Dv
+    return proj + absorb + 2 * H * (2 * R + Dr) * ctx
+
+
 def _layer_flops(cfg: ModelConfig, kind: str, ctx: int) -> float:
     D, F = cfg.d_model, cfg.d_ff
     H, K, E = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     attn_ctx = min(ctx, cfg.sliding_window) if cfg.sliding_window else ctx
     attn = 2 * D * (H * E + 2 * K * E) + 2 * H * E * D \
         + 2 * 2 * H * E * attn_ctx                     # qkv+out proj + scores/av
+    if cfg.attn_kind == "mla" and kind in ("dense", "moe"):
+        attn = _mla_flops(cfg, ctx)
     ffn = 3 * 2 * D * F
     ffn_ng = 2 * 2 * D * F
     if kind == "dense":
         return attn + ffn
     if kind == "moe":
+        # the router at full width; of the top_k picks, the share that lands
+        # on the experts held here (all of them unless experts_held is set)
         router = 2 * D * cfg.n_experts
-        return attn + router + cfg.top_k * ffn
+        expert = 3 * 2 * D * cfg.expert_d_ff
+        picks = cfg.top_k * cfg.held_experts[1] / cfg.n_experts
+        return attn + router + (picks + cfg.n_shared_experts) * expert
     if kind == "mamba":
         I, N, Hs = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
         proj = 2 * D * (2 * I + 2 * N + Hs) + 2 * I * D
@@ -138,12 +155,14 @@ def model_flops(cfg: ModelConfig, batch: int, seq: int, mode: str) -> float:
 
 
 def active_param_count(cfg: ModelConfig) -> int:
-    """Params touched per token (MoE: top_k of n_experts expert params)."""
+    """Params touched per token (MoE: of the experts held, the share of the
+    top_k picks that lands on them)."""
     n = submodel_param_count(cfg)
     if cfg.n_experts:
-        expert = 3 * cfg.d_model * cfg.d_ff            # w1,w2,w3 per expert
-        inactive = (cfg.n_experts - cfg.top_k) * expert * cfg.n_layers
-        n -= inactive
+        expert = 3 * cfg.d_model * cfg.expert_d_ff     # w1,w2,w3 per expert
+        held = cfg.held_experts[1]
+        idle = held - cfg.top_k * held / cfg.n_experts
+        n -= int(idle * expert * (cfg.n_layers - cfg.first_dense_layers))
     return n
 
 

@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distribution.sharding import hint, hint_btd
-from repro.models import mamba2, moe, xlstm
+from repro.models import mamba2, mla, moe, xlstm
 from repro.models.config import ModelConfig, Segment
 from repro.models.layers import (attn_decode, attn_fwd, attn_init,
                                  attn_prefill, ffn_fwd, ffn_init, pdtype,
@@ -38,15 +38,20 @@ def _norm_init(cfg):
 # per-layer inits
 # ---------------------------------------------------------------------------
 
+def _self_attn_init(key, cfg):
+    return mla.mla_init(key, cfg) if cfg.attn_kind == "mla" \
+        else attn_init(key, cfg)
+
+
 def _dense_layer_init(key, cfg):
     k1, k2 = jax.random.split(key)
-    return {"ln1": _norm_init(cfg), "attn": attn_init(k1, cfg),
+    return {"ln1": _norm_init(cfg), "attn": _self_attn_init(k1, cfg),
             "ln2": _norm_init(cfg), "ffn": ffn_init(k2, cfg, gated=True)}
 
 
 def _moe_layer_init(key, cfg):
     k1, k2 = jax.random.split(key)
-    return {"ln1": _norm_init(cfg), "attn": attn_init(k1, cfg),
+    return {"ln1": _norm_init(cfg), "attn": _self_attn_init(k1, cfg),
             "ln2": _norm_init(cfg), "moe": moe.moe_init(k2, cfg)}
 
 
@@ -94,17 +99,24 @@ def shared_attn_init(key, cfg: ModelConfig):
 # per-layer forwards (single layer; used inside scan)
 # ---------------------------------------------------------------------------
 
-def _dense_fwd(cfg, lp, h, positions, causal=True):
-    h = h + attn_fwd(cfg, lp["attn"], rms_norm(h, lp["ln1"], cfg.norm_eps),
-                     positions, causal=causal, window=cfg.sliding_window)
+def _self_attn_fwd(cfg, p, x, positions):
+    """Causal self-attention of a dense or MoE layer, without a cache."""
+    if cfg.attn_kind == "mla":
+        return mla.mla_fwd(cfg, p, x, positions)[0]
+    return attn_fwd(cfg, p, x, positions, window=cfg.sliding_window)
+
+
+def _dense_fwd(cfg, lp, h, positions):
+    h = h + _self_attn_fwd(cfg, lp["attn"],
+                           rms_norm(h, lp["ln1"], cfg.norm_eps), positions)
     h = h + ffn_fwd(cfg, lp["ffn"], rms_norm(h, lp["ln2"], cfg.norm_eps),
                     gated=True)
     return h
 
 
 def _moe_fwd(cfg, lp, h, positions):
-    h = h + attn_fwd(cfg, lp["attn"], rms_norm(h, lp["ln1"], cfg.norm_eps),
-                     positions, window=cfg.sliding_window)
+    h = h + _self_attn_fwd(cfg, lp["attn"],
+                           rms_norm(h, lp["ln1"], cfg.norm_eps), positions)
     mo, aux = moe.moe_fwd(cfg, lp["moe"], rms_norm(h, lp["ln2"], cfg.norm_eps))
     return h + mo, aux
 
@@ -186,6 +198,10 @@ def seg_cache_init(cfg: ModelConfig, seg: Segment, B: int, max_len: int,
     K, E = cfg.n_kv_heads, cfg.head_dim
     kv_dt = jnp.dtype(cfg.dtype)
     skv = min(max_len, cfg.sliding_window) if cfg.sliding_window else max_len
+    if seg.kind in ("dense", "moe") and cfg.attn_kind == "mla":
+        # the latent cache: normed c_kv and the roped, head-shared k_pe
+        return {"c": jnp.zeros((L, B, max_len, cfg.kv_lora_rank), kv_dt),
+                "kpe": jnp.zeros((L, B, max_len, cfg.qk_rope_dim), kv_dt)}
     if seg.kind in ("dense", "moe"):
         return {"k": jnp.zeros((L, B, skv, K, E), kv_dt),
                 "v": jnp.zeros((L, B, skv, K, E), kv_dt)}
@@ -213,8 +229,24 @@ def seg_cache_init(cfg: ModelConfig, seg: Segment, B: int, max_len: int,
 # segment stack: prefill
 # ---------------------------------------------------------------------------
 
+def _mlp(cfg, kind, lp, h, with_picks=False):
+    """The feed-forward half of a cached dense or MoE layer: (its output,
+    to be added to the residual stream ``h``; with ``with_picks`` the MoE
+    router's picks (B * S, top_k), else None)."""
+    hn = rms_norm(h, lp["ln2"], cfg.norm_eps)
+    if kind != "moe":
+        return ffn_fwd(cfg, lp["ffn"], hn), None
+    picks = None
+    if with_picks:
+        picks = moe.route(cfg, lp["moe"], hn.reshape(-1, hn.shape[-1]))[1]
+    return moe.moe_fwd(cfg, lp["moe"], hn)[0], picks
+
+
 def seg_prefill(cfg: ModelConfig, seg: Segment, sp, shared, h, positions,
-                cache, enc_out=None):
+                cache, enc_out=None, with_picks=False):
+    """(h, cache) after the segment; a dense or MoE segment with
+    ``with_picks`` also gives its router's picks, (layers, B * S, top_k)
+    for MoE layers and None for dense ones."""
     kind = seg.kind
     if kind == "shared_attn":
         lp = shared
@@ -227,23 +259,24 @@ def seg_prefill(cfg: ModelConfig, seg: Segment, sp, shared, h, positions,
 
     if kind in ("dense", "moe"):
         def body(hh, xs):
-            lp, ck, cv = xs
+            lp, lc = xs
             hh = hint_btd(hh)
-            a, ck2, cv2 = attn_prefill(cfg, lp["attn"],
-                                       rms_norm(hh, lp["ln1"], cfg.norm_eps),
-                                       positions, ck, cv,
-                                       window=cfg.sliding_window)
-            hh = hh + a
-            hn = rms_norm(hh, lp["ln2"], cfg.norm_eps)
-            if kind == "moe":
-                mo, _ = moe.moe_fwd(cfg, lp["moe"], hn)
-                hh = hh + mo
+            hn = rms_norm(hh, lp["ln1"], cfg.norm_eps)
+            if cfg.attn_kind == "mla":
+                a, c2, p2 = mla.mla_prefill(cfg, lp["attn"], hn, positions,
+                                            lc["c"], lc["kpe"])
+                lc2 = {"c": c2, "kpe": p2}
             else:
-                hh = hh + ffn_fwd(cfg, lp["ffn"], hn)
-            return hh, (ck2, cv2)
+                a, ck2, cv2 = attn_prefill(cfg, lp["attn"], hn, positions,
+                                           lc["k"], lc["v"],
+                                           window=cfg.sliding_window)
+                lc2 = {"k": ck2, "v": cv2}
+            hh = hh + a
+            out, picks = _mlp(cfg, kind, lp, hh, with_picks)
+            return hh + out, (lc2, picks)
 
-        h, (ck, cv) = jax.lax.scan(body, h, (sp, cache["k"], cache["v"]))
-        return h, {"k": ck, "v": cv}
+        h, (c, picks) = jax.lax.scan(body, h, (sp, cache))
+        return (h, c, picks) if with_picks else (h, c)
 
     if kind == "mamba":
         def body(hh, xs):
@@ -300,7 +333,10 @@ def seg_prefill(cfg: ModelConfig, seg: Segment, sp, shared, h, positions,
 # segment stack: decode (one token)
 # ---------------------------------------------------------------------------
 
-def seg_decode(cfg: ModelConfig, seg: Segment, sp, shared, h1, pos, cache):
+def seg_decode(cfg: ModelConfig, seg: Segment, sp, shared, h1, pos, cache,
+               with_picks=False):
+    """(h1, cache) after the segment; ``with_picks`` as in
+    :func:`seg_prefill`."""
     kind = seg.kind
     if kind == "shared_attn":
         lp = shared
@@ -313,22 +349,24 @@ def seg_decode(cfg: ModelConfig, seg: Segment, sp, shared, h1, pos, cache):
 
     if kind in ("dense", "moe"):
         def body(hh, xs):
-            lp, ck, cv = xs
+            lp, lc = xs
             hh = hint_btd(hh)
-            a, ck2, cv2 = attn_decode(cfg, lp["attn"],
-                                      rms_norm(hh, lp["ln1"], cfg.norm_eps),
-                                      pos, ck, cv, window=cfg.sliding_window)
-            hh = hh + a
-            hn = rms_norm(hh, lp["ln2"], cfg.norm_eps)
-            if kind == "moe":
-                mo, _ = moe.moe_fwd(cfg, lp["moe"], hn)
-                hh = hh + mo
+            hn = rms_norm(hh, lp["ln1"], cfg.norm_eps)
+            if cfg.attn_kind == "mla":
+                a, c2, p2 = mla.mla_decode(cfg, lp["attn"], hn, pos,
+                                           lc["c"], lc["kpe"])
+                lc2 = {"c": c2, "kpe": p2}
             else:
-                hh = hh + ffn_fwd(cfg, lp["ffn"], hn)
-            return hh, (ck2, cv2)
+                a, ck2, cv2 = attn_decode(cfg, lp["attn"], hn, pos,
+                                          lc["k"], lc["v"],
+                                          window=cfg.sliding_window)
+                lc2 = {"k": ck2, "v": cv2}
+            hh = hh + a
+            out, picks = _mlp(cfg, kind, lp, hh, with_picks)
+            return hh + out, (lc2, picks)
 
-        h1, (ck, cv) = jax.lax.scan(body, h1, (sp, cache["k"], cache["v"]))
-        return h1, {"k": ck, "v": cv}
+        h1, (c, picks) = jax.lax.scan(body, h1, (sp, cache))
+        return (h1, c, picks) if with_picks else (h1, c)
 
     if kind == "mamba":
         def body(hh, xs):

@@ -17,6 +17,7 @@ import numpy as np
 from repro.models import model as M
 from repro.models import partition
 from repro.models.config import build_plan, submodel_plan
+from repro.obs.tracing import TRACER
 from repro.serving.loader import PodCache, WeightStore
 
 
@@ -48,6 +49,8 @@ class EdgePod:
 
     # -- actual execution ------------------------------------------------
     def _fns(self, model: str, exit_idx: int, batch: int, max_len: int):
+        """(prefill, decode, plan) compiled for one batch shape; the
+        entry's fourth program makes that shape's empty cache."""
         key = (model, exit_idx, batch, max_len)
         if key not in self._decode_fns:
             cfg = self.cache.store.cfgs[model]
@@ -58,8 +61,10 @@ class EdgePod:
             dc = jax.jit(lambda p, t, pos, c: M.decode(cfg, p, t, pos, c,
                                                        exit_idx=exit_idx,
                                                        plan=plan))
-            self._decode_fns[key] = (pf, dc, plan)
-        return self._decode_fns[key]
+            sub = submodel_plan(plan, exit_idx)
+            mk = jax.jit(lambda: M.cache_init(cfg, batch, max_len, sub))
+            self._decode_fns[key] = (pf, dc, plan, mk)
+        return self._decode_fns[key][:3]
 
     def serve_batch(self, model: str, reqs: list, now: float):
         """Run real generation for a batch of same-model requests."""
@@ -72,8 +77,10 @@ class EdgePod:
         B = len(reqs)
         max_len = prompt + max_new
         pf, dc, plan = self._fns(model, exit_idx, B, max_len)
-        sub = submodel_plan(plan, exit_idx)
-        cache = M.cache_init(cfg, B, max_len, sub)
+        # the cache is made by a compiled program and the decode position
+        # passed as a NumPy scalar: no eager op of the serving loop is
+        # traced again if JAX's dispatch caches miss
+        cache = self._decode_fns[(model, exit_idx, B, max_len)][3]()
         toks = np.zeros((B, prompt), np.int32)
         for i, r in enumerate(reqs):
             toks[i, -len(r.tokens):] = r.tokens     # left-pad with 0
@@ -84,14 +91,22 @@ class EdgePod:
         if cfg.family == "vlm":
             batch["patches"] = jnp.zeros((B, cfg.frontend_len, cfg.d_model),
                                          jnp.dtype(cfg.dtype))
-        logits, kv = pf(params, batch, cache)
-        outs = [[] for _ in reqs]
-        tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-        for step in range(max_new):
-            for i in range(B):
-                outs[i].append(int(tok[i, 0]))
-            logits, kv = dc(params, tok, jnp.int32(prompt + step), kv)
+        # program spans: the prefill until its token is on the host, and
+        # the decode steps until the last one's token is (``max_new``
+        # steps, of which the last one's token is not served)
+        with TRACER.span("serve.prefill", count_retraces=False, batch=B,
+                         tokens=B * prompt, exit=exit_idx):
+            logits, kv = pf(params, batch, cache)
             tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+            outs = [[t] for t in np.asarray(tok)[:, 0].tolist()]
+        with TRACER.span("serve.decode", count_retraces=False,
+                         steps=max_new):
+            for step in range(max_new):
+                logits, kv = dc(params, tok, np.int32(prompt + step), kv)
+                tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+                for o, t in zip(outs, np.asarray(tok)[:, 0].tolist()):
+                    o.append(t)
+        outs = [o[:max_new] for o in outs]
 
         # simulated service time from the catalog's FLOPs model
         c_h = partition.submodel_flops_per_token(cfg, exit_idx, ctx=prompt)
