@@ -1,15 +1,20 @@
-"""Held routed experts of one MoE layer: the grouped product over every
-(token, pick) pair (``moe.held_experts_fwd``, ``jax.lax.ragged_dot``)
-against a dense product of every token through every held expert,
-weighed by its routing weight (zero where not picked).
+"""Held routed experts of one MoE layer, three forms of one sum: the
+grouped product over the held (token, pick) pairs in chunks of
+``moe.held_rows`` sorted rows (``moe.held_experts_fwd``), the grouped
+product over all T x top_k sorted pairs at once with the rest selected
+away (``ragged``, the form before the chunks), and a dense product of
+every token through every held expert, weighed by its routing weight
+(zero where not picked).
 
 At Moonlight-16B-A3B's widths (hidden 2048, experts of width 1408, 6
 picks of 64 by the V3 router, 8 held) in bfloat16, for decode batches
 and 2k-token prefills.  Each form is compiled once, then timed over
-``--reps`` calls back to back, interleaved with the other form; the
-least of ``--rounds`` rounds is kept.  One JSON line per shape:
-``tokens``, ``ragged_ms``, ``dense_ms``, and the largest difference
-of the two outputs relative to the output's largest entry.
+``--reps`` calls back to back, interleaved with the others; the least of
+``--rounds`` rounds is kept.  One JSON line per shape: ``tokens``,
+``chunked_ms``, ``ragged_ms``, ``dense_ms``, the held pairs the router
+gave (``held_pairs``), the chunks they take (``chunks``) and the rows the
+chunked form runs (``rows_run``), and the largest difference of each
+form's output from the ragged form's, relative to its largest entry.
 
     PYTHONPATH=src python -m benchmarks.bench_moe_dispatch [--smoke]
 """
@@ -21,9 +26,33 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import configs
 from repro.models import moe
+
+
+def ragged_held(cfg, p, xf, idx, w):
+    """The grouped products over all T x top_k sorted pairs, the held
+    ones first, the rows past their groups selected away."""
+    T, D = xf.shape
+    k = idx.shape[-1]
+    first, n = cfg.held_experts
+    local = idx.reshape(-1) - first
+    held = (local >= 0) & (local < n)
+    eid = jnp.where(held, local, n)
+    order = jnp.argsort(eid, stable=True)
+    sizes = jnp.zeros((n + 1,), jnp.int32).at[eid].add(1)[:n]
+    tok = order // k
+    xs = xf[tok]
+    dt = xf.dtype
+    h = jax.lax.ragged_dot(xs, p["w1"].astype(dt), sizes)
+    g = jax.lax.ragged_dot(xs, p["w3"].astype(dt), sizes)
+    y = jax.lax.ragged_dot(jax.nn.silu(h) * g, p["w2"].astype(dt), sizes)
+    out = jnp.where(held[order][:, None],
+                    y.astype(jnp.float32) * w.reshape(-1)[order][:, None],
+                    0.0)
+    return jnp.zeros((T, D), jnp.float32).at[tok].add(out).astype(dt)
 
 
 def dense_held(cfg, p, xf, idx, w):
@@ -70,22 +99,29 @@ def main(argv=None) -> int:
             return fwd(cfg, p, xf, idx, w)
         return jax.jit(f)
 
-    ragged, dense = routed(moe.held_experts_fwd), routed(dense_held)
+    forms = {"chunked": routed(moe.held_experts_fwd),
+             "ragged": routed(ragged_held), "dense": routed(dense_held)}
+    first, n = cfg.held_experts
     for T in tokens:
         xf = jax.random.normal(jax.random.fold_in(key, T),
                                (T, cfg.d_model)).astype(jnp.dtype(cfg.dtype))
-        a = jax.block_until_ready(ragged(p, xf))
-        b = jax.block_until_ready(dense(p, xf))
-        diff = float(jnp.max(jnp.abs(a.astype(jnp.float32)
-                                     - b.astype(jnp.float32)))
-                     / (jnp.max(jnp.abs(a.astype(jnp.float32))) + 1e-30))
-        best = {"ragged_ms": float("inf"), "dense_ms": float("inf")}
+        idx = np.asarray(jax.jit(lambda p, xf: moe.route(cfg, p, xf)[1])(
+            p, xf))
+        chunks = int(moe.held_chunks(cfg, idx))
+        line = {"tokens": T,
+                "held_pairs": int(np.sum((idx >= first) & (idx < first + n))),
+                "chunks": chunks, "rows_run": chunks * moe.held_rows(cfg, T)}
+        outs = {f: np.asarray(jax.block_until_ready(fn(p, xf)), np.float32)
+                for f, fn in forms.items()}
+        for f in ("chunked", "dense"):
+            line[f"{f}_rel_diff"] = float(
+                np.max(np.abs(outs[f] - outs["ragged"]))
+                / (np.max(np.abs(outs["ragged"])) + 1e-30))
+        best = dict.fromkeys(forms, float("inf"))
         for _ in range(args.rounds):
-            best["ragged_ms"] = min(best["ragged_ms"],
-                                    _timed(ragged, (p, xf), args.reps))
-            best["dense_ms"] = min(best["dense_ms"],
-                                   _timed(dense, (p, xf), args.reps))
-        print(json.dumps({"tokens": T, **best, "rel_diff": diff,
+            for f, fn in forms.items():
+                best[f] = min(best[f], _timed(fn, (p, xf), args.reps))
+        print(json.dumps({**line, **{f"{f}_ms": v for f, v in best.items()},
                           "device": jax.devices()[0].device_kind}),
               flush=True)
     return 0

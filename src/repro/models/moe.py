@@ -16,17 +16,22 @@ as every chip of an expert-parallel deployment computes it.
 
 The layer holds the routed experts ``experts_held`` = (first, count) and
 computes only their contribution: the (token, pick) pairs that land on a
-held expert are sorted by expert and run through one grouped product per
-matrix (``jax.lax.ragged_dot``).  Every pair is computed: no token is
-dropped at any batch or length.  Pairs routed elsewhere get nothing here;
-the chips that hold those experts (absent on one chip) would add theirs.
-The shared experts, one SwiGLU of ``n_shared_experts * moe_d_ff``, are
-added once for every token.
+held expert are sorted first, grouped by expert, and run through the
+grouped products (``jax.lax.ragged_dot``) in chunks of :func:`held_rows`
+sorted rows, chunk after chunk until every held pair is covered.  A chunk
+is 1.25 times the held pairs that uniform routing gives, so one chunk
+covers them at the usual routing and a skewed router takes more; where
+every expert is held, and at decode, one chunk is every pair.  Every pair
+is computed: no token is dropped at any batch, length or routing.  Pairs
+routed elsewhere get nothing here; the chips that hold those experts
+(absent on one chip) would add theirs.  The shared experts, one SwiGLU of
+``n_shared_experts * moe_d_ff``, are added once for every token.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.config import ModelConfig
 from repro.models.layers import ffn_fwd, pdtype
@@ -77,9 +82,31 @@ def route(cfg: ModelConfig, p, xf):
     return scores, idx, w * cfg.routed_scale
 
 
+def held_rows(cfg: ModelConfig, tokens: int) -> int:
+    """Rows of one chunk of the held experts' grouped products for
+    ``tokens`` tokens: 1.25 times the held (token, pick) pairs of uniform
+    routing, ``tokens * top_k * count / n_experts``, rounded up to whole
+    128-row tiles, and never more than all ``tokens * top_k`` pairs."""
+    _, n = cfg.held_experts
+    pairs = tokens * cfg.top_k
+    want = -(-5 * pairs * n // (4 * cfg.n_experts))
+    return min(pairs, -(-want // 128) * 128)
+
+
+def held_chunks(cfg: ModelConfig, picks) -> np.ndarray:
+    """Chunks of :func:`held_rows` rows that each MoE layer runs for the
+    router's ``picks`` (..., tokens, top_k): one for every ``held_rows``
+    held pairs or part of it, and at least one."""
+    picks = np.asarray(picks)
+    first, n = cfg.held_experts
+    held = np.sum((picks >= first) & (picks < first + n), axis=(-2, -1))
+    return np.maximum(1, -(-held // held_rows(cfg, picks.shape[-2])))
+
+
 def held_experts_fwd(cfg: ModelConfig, p, xf, idx, w):
     """The held experts' part of the routed output for xf (T, D): every
-    (token, pick) pair on a held expert, grouped by expert."""
+    (token, pick) pair on a held expert, grouped by expert, run in chunks
+    of :func:`held_rows` sorted rows until every one is covered."""
     T, D = xf.shape
     k = idx.shape[-1]
     first, n = cfg.held_experts
@@ -88,18 +115,42 @@ def held_experts_fwd(cfg: ModelConfig, p, xf, idx, w):
     eid = jnp.where(held, local, n)               # the rest sort after
     order = jnp.argsort(eid, stable=True)
     sizes = jnp.zeros((n + 1,), jnp.int32).at[eid].add(1)[:n]
-    tok = order // k
-    xs = xf[tok]
+    cum = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes)])
+    total = cum[-1]
+    wf = w.reshape(-1)
     dt = xf.dtype
-    h = jax.lax.ragged_dot(xs, p["w1"].astype(dt), sizes)
-    g = jax.lax.ragged_dot(xs, p["w3"].astype(dt), sizes)
-    y = jax.lax.ragged_dot(jax.nn.silu(h) * g, p["w2"].astype(dt), sizes)
-    # the grouped product leaves the rows past its groups undefined (any
-    # value, NaN too, may be there): select the held pairs, never scale
-    out = jnp.where(held[order][:, None],
-                    y.astype(jnp.float32) * w.reshape(-1)[order][:, None],
-                    0.0)
-    return jnp.zeros((T, D), jnp.float32).at[tok].add(out).astype(dt)
+    C = held_rows(cfg, T)
+    if C < T * k:
+        # the last chunk may run past the pairs: pad so that its slice is
+        # not moved back
+        order = jnp.pad(order, (0, C))
+
+    def chunk(s, acc):
+        """The sorted rows [s, s + C): each group cut to the chunk."""
+        rows = jax.lax.dynamic_slice_in_dim(order, s, C)
+        part = jnp.clip(cum[1:] - s, 0, C) - jnp.clip(cum[:-1] - s, 0, C)
+        tok = rows // k
+        xs = xf[tok]
+        h = jax.lax.ragged_dot(xs, p["w1"].astype(dt), part)
+        g = jax.lax.ragged_dot(xs, p["w3"].astype(dt), part)
+        y = jax.lax.ragged_dot(jax.nn.silu(h) * g, p["w2"].astype(dt), part)
+        # the grouped product leaves the rows past its groups undefined
+        # (any value, NaN too, may be there): select the held pairs, never
+        # scale
+        out = jnp.where((s + jnp.arange(C) < total)[:, None],
+                        y.astype(jnp.float32) * wf[rows][:, None], 0.0)
+        return acc.at[tok].add(out)
+
+    acc = jnp.zeros((T, D), jnp.float32)
+    if C == T * k:                  # every expert held, or decode: one chunk
+        return chunk(0, acc).astype(dt)
+    n_chunks = jnp.maximum(1, (total + C - 1) // C)
+    # a static trip count, enough for every pair held, so that the loop can
+    # be differentiated; the chunks past the held pairs are skipped
+    acc = jax.lax.fori_loop(
+        0, -(-T * k // C), lambda i, a: jax.lax.cond(
+            i < n_chunks, lambda a: chunk(i * C, a), lambda a: a, a), acc)
+    return acc.astype(dt)
 
 
 def moe_fwd(cfg: ModelConfig, p, x):

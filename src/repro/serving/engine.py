@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import model as M
-from repro.models import partition
+from repro.models import moe, partition
 from repro.models.config import build_plan, submodel_plan
 from repro.obs.tracing import TRACER
 from repro.serving.loader import PodCache, WeightStore
@@ -93,9 +93,12 @@ class EdgePod:
                                          jnp.dtype(cfg.dtype))
         # program spans: the prefill until its token is on the host, and
         # the decode steps until the last one's token is (``max_new``
-        # steps, of which the last one's token is not served)
-        with TRACER.span("serve.prefill", count_retraces=False, batch=B,
-                         tokens=B * prompt, exit=exit_idx):
+        # steps, of which the last one's token is not served); a MoE
+        # model's prefill also names the rows of its held experts' chunk
+        attrs = dict(batch=B, tokens=B * prompt, exit=exit_idx)
+        if cfg.n_experts:
+            attrs["moe_rows"] = moe.held_rows(cfg, B * prompt)
+        with TRACER.span("serve.prefill", count_retraces=False, **attrs):
             logits, kv = pf(params, batch, cache)
             tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
             outs = [[t] for t in np.asarray(tok)[:, 0].tolist()]
