@@ -121,8 +121,8 @@ def bench_throughput(n_users=None, n_seeds=None, best_of=8, iters=1500):
                                  u_cat[:, sl], u_phi[:, sl], n_seeds=1)
     t_host_loop = time.time() - t0
 
-    # quality: same algorithm either way; LP backends differ only in the
-    # fused kernel's f64 vs the batched solver's f32 iterates
+    # quality: the same algorithm on both paths, each from its own f64
+    # LP solve
     prec_dev = np.asarray(dev["metrics"]["avg_precision"]).mean()
     prec_host = np.mean([[ih["metrics"]["avg_precision"]
                           for _, _, ih in per] for per in host])

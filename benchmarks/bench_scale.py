@@ -101,9 +101,10 @@ def bench_equivalence(n_variants=16, n_users=40, n_seeds=2, best_of=4,
     insts = _grid_insts(n_variants, n_users)
     kw = dict(kind="offline", insts=insts, seed=0, n_seeds=n_seeds,
               best_of=best_of, pdhg_iters=iters)
-    ref = run_grid(GridSpec(**kw, backend="vmap", max_buckets=1))
-    bkt = run_grid(GridSpec(**kw, backend="vmap", max_buckets=3))
-    shd = run_grid(GridSpec(**kw, backend="sharded", max_buckets=3,
+    ref = run_grid(GridSpec(**kw, max_buckets=1))
+    bkt = run_grid(GridSpec(**kw, max_buckets=3))
+    shd = run_grid(GridSpec(**kw, devices=len(jax.devices()),
+                            max_buckets=3,
                             chunk_size=max(n_variants // 2, N_DEVICES)))
     identical_b, obj_b, met_b = _compare_offline(ref.results, bkt.results)
     identical_s, obj_s, met_s = _compare_offline(ref.results, shd.results)
@@ -113,8 +114,8 @@ def bench_equivalence(n_variants=16, n_users=40, n_seeds=2, best_of=4,
 
     jobs = _online_jobs()
     ocfg = OnlineConfig(n_slots=12, rounds=2)
-    on_ref = run_online_grid(jobs, ocfg, backend="vmap")
-    on_shd = run_online_grid(jobs, ocfg, backend="sharded")
+    on_ref = run_online_grid(jobs, ocfg)
+    on_shd = run_online_grid(jobs, ocfg, devices=len(jax.devices()))
     online_identical = all(
         np.array_equal(a["slot_qoe"], b["slot_qoe"])
         and np.array_equal(a["final_state"].lvl, b["final_state"].lvl)
@@ -149,15 +150,16 @@ def bench_throughput(n_variants=None, n_users=40, n_seeds=2, best_of=8,
     chunk = max(n_variants // 4, N_DEVICES)
 
     # warm both compile caches, then measure steady state
-    run_grid(GridSpec(**kw, backend="vmap"))
+    run_grid(GridSpec(**kw))
     with TRACER.span("scale:one_device", variants=n_variants) as sp:
-        one_dev = run_grid(GridSpec(**kw, backend="vmap"))
+        one_dev = run_grid(GridSpec(**kw))
     t_vmap = sp.seconds
 
-    run_grid(GridSpec(**kw, backend="sharded", chunk_size=chunk))
+    sharded = dict(kw, devices=len(jax.devices()), chunk_size=chunk)
+    run_grid(GridSpec(**sharded))
     with TRACER.span("scale:sharded", variants=n_variants,
                      chunk=chunk) as sp:
-        shd = run_grid(GridSpec(**kw, backend="sharded", chunk_size=chunk))
+        shd = run_grid(GridSpec(**sharded))
     t_shard = sp.seconds
 
     identical, obj_gap, met_gap = _compare_offline(one_dev.results,

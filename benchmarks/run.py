@@ -8,8 +8,8 @@ from __future__ import annotations
 
 import time
 
-from benchmarks import (bench_baselines, bench_kernels, bench_lp,
-                        bench_offline, bench_online, bench_serving, common,
+from benchmarks import (bench_baselines, bench_kernels, bench_offline,
+                        bench_online, bench_serving, common,
                         motivating_example, roofline, tables)
 
 
@@ -65,7 +65,6 @@ def main() -> None:
                    f"total_s={sw['seconds']:.2f}")
 
     bench_serving.main()
-    bench_lp.main()
     bench_online.main()
     bench_offline.main()
     bench_baselines.main()

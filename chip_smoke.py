@@ -6,9 +6,9 @@ requests, on a TPU.
                                       # chips against the one-chip dispatch
 
 Phase 1 decides one CoCaR window at the paper's Sec. VII-A deployment
-(``MECConfig()``: N=5, U=600, M=8, 500 MB, Zipf 0.8) on both LP backends
-and checks the decisions: feasible, identical to the NumPy oracle run on
-the device's own fractional solution, and identical across backends.
+(``MECConfig()``: N=5, U=600, M=8, 500 MB, Zipf 0.8) and checks the
+decisions: feasible, and identical to the NumPy oracle run on the
+device's own fractional solution.
 Phase 2 runs CoCaR-OL for 20 slots on the scan engine against the NumPy
 engine.  Phase 3 serves qwen1.5-0.5b at its published widths (random
 weights from ``--seed``) from a CoCaR plan on four pods of the chip and
@@ -64,18 +64,18 @@ def _first_diff(a, b):
     return None if not len(diff) else tuple(int(i) for i in diff[0][:2])
 
 
-def _check_identical(what, x_a, A_a, t_a, x_b, A_b, t_b, inst, frac_a,
-                     frac_b, u_cat, u_phi):
+def _check_identical(what, x_a, A_a, t_a, x_b, A_b, t_b, inst, frac,
+                     u_cat, u_phi):
     """Decision identity of two (x, A, best trial) triples.  On a
     mismatch, print the first differing (BS, model) / (BS, user) and the
-    rounding margins of the fractional solutions behind them, then fail."""
+    rounding margins of the fractional solution behind them, then fail."""
     import numpy as np
 
     same = (np.array_equal(x_a, x_b) and np.array_equal(A_a, A_b)
             and t_a == t_b)
     if same:
         return
-    from harness import decision_margin, threshold_shift_certificate
+    from harness import decision_margin
 
     oh = inst.onehot_mu()
     N, U = inst.N, inst.U
@@ -85,9 +85,7 @@ def _check_identical(what, x_a, A_a, t_a, x_b, A_b, t_b, inst, frac_a,
         "first_bs_model": _first_diff(x_a, x_b),
         "first_bs_user": _first_diff(A_a, A_b),
         "best_trial": [int(t_a), int(t_b)],
-        "margin": decision_margin(*frac_a, oh, uc, up),
-        "certificate": threshold_shift_certificate(*frac_a, *frac_b, oh,
-                                                   uc, up)},
+        "margin": decision_margin(*frac, oh, uc, up)},
         default=float), flush=True)
     raise SystemExit(f"{what}: decisions differ")
 
@@ -95,19 +93,6 @@ def _check_identical(what, x_a, A_a, t_a, x_b, A_b, t_b, inst, frac_a,
 # ---------------------------------------------------------------------------
 # phase 1: offline control plane
 # ---------------------------------------------------------------------------
-
-def _has_mosaic_kernel(stacked, u_cat, u_phi, pdhg_iters):
-    """Whether the compiled pallas-backend pipeline holds the Mosaic
-    PDHG kernel (a ``tpu_custom_call``)."""
-    import jax
-
-    from repro.core import cocar as CC
-
-    with jax.enable_x64(True):
-        hlo = CC._pipeline_jitted("pallas").lower(
-            stacked.data, u_cat, u_phi, pdhg_iters, 1).compile().as_text()
-    return "tpu_custom_call" in hlo
-
 
 def phase_offline(seed: int, cfg=None, pdhg_iters: int = 4000,
                   best_of: int = 8):
@@ -121,50 +106,31 @@ def phase_offline(seed: int, cfg=None, pdhg_iters: int = 4000,
     stacked = stack_instances([inst])
     N, U = inst.N, inst.U
     u_cat, u_phi = CC.offline_uniforms(stacked, seed, 1, best_of)
-    decided = {}
-    for backend in ("reference", "pallas"):
-        def decide():
-            return CC.cocar_grid([inst], seed=seed, pdhg_iters=pdhg_iters,
-                                 best_of=best_of, lp_backend=backend)
 
-        grid, setup_s = _timed(decide)
-        _, warm_s = _timed(decide)
-        x, A, info = grid[0][0]
-        dev = CC.offline_pipeline_device(stacked, u_cat, u_phi, pdhg_iters,
-                                         1, lp_backend=backend)
-        frac = (dev["x_frac"][0, :N], dev["A_frac"][0, :N, :U])
-        _check_identical(f"cocar_grid vs offline_pipeline_device "
-                         f"({backend})", x, A, info["best_t"],
-                         dev["x"][0, 0, :N], dev["A"][0, 0, :N, :U],
-                         int(dev["best_t"][0, 0]), inst, frac, frac,
-                         u_cat, u_phi)
-        feas = check_feasible(inst, x, A)
-        if not feas["ok"]:
-            raise SystemExit(f"phase 1 ({backend}): infeasible {feas}")
-        xh, Ah, ih = CC.offline_pipeline_host(stacked, dev["x_frac"],
-                                              dev["A_frac"], u_cat,
-                                              u_phi)[0][0]
-        _check_identical(f"device vs NumPy oracle ({backend})", x, A,
-                         info["best_t"], xh, Ah, ih["best_t"], inst, frac,
-                         frac, u_cat, u_phi)
-        line = {}
-        if backend == "pallas":
-            line["tpu_custom_call"] = _has_mosaic_kernel(
-                stacked, u_cat, u_phi, pdhg_iters)
-            if not line["tpu_custom_call"]:
-                raise SystemExit("phase 1: the pallas LP backend compiled "
-                                 "without its Mosaic kernel")
-        decided[backend] = (x, A, info["best_t"], frac)
-        _phase_line(f"offline:{backend}", setup_s, warm_s,
-                    window=[N, U, inst.M], feasible=True,
-                    identical_to_numpy_oracle=True,
-                    avg_precision=info["metrics"]["avg_precision"],
-                    lp_obj=info["lp_obj"], **line)
-    r, p = decided["reference"], decided["pallas"]
-    _check_identical("reference vs pallas LP backend", r[0], r[1], r[2],
-                     p[0], p[1], p[2], inst, r[3], p[3], u_cat, u_phi)
-    print(json.dumps({"phase": "offline", "identical_across_backends":
-                      True}), flush=True)
+    def decide():
+        return CC.cocar_grid([inst], seed=seed, pdhg_iters=pdhg_iters,
+                             best_of=best_of)
+
+    grid, setup_s = _timed(decide)
+    _, warm_s = _timed(decide)
+    x, A, info = grid[0][0]
+    dev = CC.offline_pipeline_device(stacked, u_cat, u_phi, pdhg_iters, 1)
+    frac = (dev["x_frac"][0, :N], dev["A_frac"][0, :N, :U])
+    _check_identical("cocar_grid vs offline_pipeline_device", x, A,
+                     info["best_t"], dev["x"][0, 0, :N],
+                     dev["A"][0, 0, :N, :U], int(dev["best_t"][0, 0]),
+                     inst, frac, u_cat, u_phi)
+    feas = check_feasible(inst, x, A)
+    if not feas["ok"]:
+        raise SystemExit(f"phase 1: infeasible {feas}")
+    xh, Ah, ih = CC.offline_pipeline_host(stacked, dev["x_frac"],
+                                          dev["A_frac"], u_cat, u_phi)[0][0]
+    _check_identical("device vs NumPy oracle", x, A, info["best_t"], xh,
+                     Ah, ih["best_t"], inst, frac, u_cat, u_phi)
+    _phase_line("offline", setup_s, warm_s, window=[N, U, inst.M],
+                feasible=True, identical_to_numpy_oracle=True,
+                avg_precision=info["metrics"]["avg_precision"],
+                lp_obj=info["lp_obj"])
 
 
 # ---------------------------------------------------------------------------
@@ -344,7 +310,7 @@ def phase_sharded(seed: int, n_chips: int, base=None,
     kw = dict(seed=seed, pdhg_iters=pdhg_iters, best_of=best_of)
 
     def sharded():
-        return cocar_grid(insts, backend="sharded", devices=n_chips, **kw)
+        return cocar_grid(insts, devices=n_chips, **kw)
 
     out, setup_s = _timed(sharded)
     t_warm = time.perf_counter()
@@ -352,7 +318,7 @@ def phase_sharded(seed: int, n_chips: int, base=None,
     devices = sorted({d for sp in TRACER.since(t_warm)
                       if sp.name == "chunk" for d in sp.attrs["devices"]})
     def one_chip():
-        return cocar_grid(insts, backend="device", **kw)
+        return cocar_grid(insts, **kw)
 
     one, one_setup_s = _timed(one_chip)
     _, one_warm_s = _timed(one_chip)

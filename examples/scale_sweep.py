@@ -23,6 +23,7 @@ import argparse                                             # noqa: E402
 import resource                                             # noqa: E402
 from dataclasses import replace                             # noqa: E402
 
+import jax                                                  # noqa: E402
 import numpy as np                                          # noqa: E402
 
 from repro.core.cocar import OFFLINE_POLICIES, improvement_ratio  # noqa: E402
@@ -60,7 +61,8 @@ def progress(ev):
 
 spec = GridSpec(kind="policy", insts=insts, seed=0, n_seeds=N_SEEDS,
                 best_of=8, pdhg_iters=1200, episodes=EPISODES,
-                backend="sharded", chunk_size=max(N_VARIANTS // 8, 8),
+                devices=len(jax.devices()),
+                chunk_size=max(N_VARIANTS // 8, 8),
                 max_buckets=4, progress=progress)
 
 print(f"{N_VARIANTS} variants x {N_SEEDS} seeds x {len(OFFLINE_POLICIES)} "

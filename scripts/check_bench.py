@@ -144,31 +144,6 @@ MANIFEST = {
                         "offline.n_users", "offline.n_windows",
                         "offline.pdhg_iters", "offline.duration_s"],
     },
-    "BENCH_lp.json": {
-        "scale": ["step.iters", "step.n_users_max", "grid.variants",
-                  "grid.n_users", "grid.pdhg_iters"],
-        "ratios": ["step.fused_speedup_u1000", "solve.fused_speedup",
-                   "grid.grid_speedup"],
-        "gaps": ["grid.decision_gap"],
-        # the fused LP backend's contract: >= 3x reference step time at
-        # U=1000 (target_3x_met; the bench itself asserts it), identical
-        # offline-grid decisions, and the per-comparison threshold-shift
-        # certificate that *implies* the identity (margin_certified) —
-        # the CI smoke produces the grid flags; the step flag exists on
-        # full-scale runs
-        "flags": ["step.target_3x_met", "grid.decisions_identical",
-                  "grid.margin_certified"],
-        # PDHG convergence telemetry (repro.obs): the truncated bench
-        # budgets legitimately stop above DEFAULT_TOL, so the final
-        # residuals are drift-gated against the baseline instead of
-        # flag-gated — a residual that moves >50% at an identical budget
-        # means the solver's convergence behaviour changed
-        "drifts": [("grid.pdhg_final_residual", 0.5),
-                   ("solve.pdhg_final_residual", 0.5)],
-        "drift_scale": ["grid.variants", "grid.n_users",
-                        "grid.pdhg_iters", "solve.n_users",
-                        "solve.iters"],
-    },
     "BENCH_scale.json": {
         "scale": ["throughput.variants", "throughput.n_seeds",
                   "throughput.n_users", "throughput.pdhg_iters",

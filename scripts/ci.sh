@@ -43,13 +43,6 @@ JAX_ENABLE_X64=1 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
     python -m benchmarks.bench_users --smoke
 JAX_ENABLE_X64=1 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
     python -m benchmarks.bench_baselines --smoke
-# the fused LP backend's conformance smoke: lp_backend="pallas" must
-# reproduce the reference backend's offline-grid decisions bit-exactly,
-# with the per-comparison threshold-shift certificate holding.  No
-# JAX_ENABLE_X64 here: the bench scopes x64 internally per block, the
-# same way the production pipeline does.
-PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
-    python -m benchmarks.bench_lp --smoke
 # the sharded grid executor under a forced 8-device host mesh: shard_map
 # + bucketed batching + chunk streaming must reproduce the one-device
 # dispatch's decisions exactly (the flag is also set inside bench_scale
@@ -78,7 +71,7 @@ python scripts/check_metrics.py results/bench/ci/BENCH_serving.metrics.prom
 # serving smoke above writes the fresh copy into results/bench/ci)
 XLA_FLAGS=--xla_force_host_platform_device_count=2 \
     PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
-    python -m repro.experiments.sweep --smoke --shard
+    python -m repro.experiments.sweep --smoke --devices 2
 python scripts/report.py results/sweep/ci results/bench/ci \
     --check-converged | tee /tmp/obs_report.txt
 grep -q "== Convergence" /tmp/obs_report.txt \

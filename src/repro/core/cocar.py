@@ -5,8 +5,9 @@ window-by-window offline driver.
 offline pipeline — LP (PDHG) → randomized rounding → repair → trial
 argmax → window metrics — is a single jitted/vmapped device dispatch over
 (windows × rounding seeds × best_of trials): ``offline_pipeline_device``,
-driven by ``cocar_windows_batched(backend="device")`` and the sweep
-harness (``repro.experiments.sweep``).
+run through the ``repro.scale`` grid executor by ``cocar_grid``,
+``cocar_windows_batched`` and the sweep harness
+(``repro.experiments.sweep``).
 
 ``offline_pipeline_host`` is the NumPy reference of the same computation
 (per-window Python loops over seeds and trials).  Both consume the same
@@ -26,7 +27,7 @@ from repro.core.jdcr import JDCRInstance, objective_sel
 from repro.core.rounding import (draw_rounding_uniforms, repair,
                                  repair_device, round_from_uniforms)
 from repro.mec import metrics as MET
-from repro.mec.scenario import MECConfig, Scenario, StackedWindows, stack_instances
+from repro.mec.scenario import MECConfig, Scenario, StackedWindows
 from repro.obs.diagnostics import lp_diag_summary
 from repro.obs.tracing import register_jit
 
@@ -71,13 +72,11 @@ def cocar_window(inst: JDCRInstance, seed: int = 0, solver: str = "scipy",
 # ---------------------------------------------------------------------------
 
 def _pipeline_kernel(data, u_cat, u_phi, iters, n_seeds,
-                     backend: str = "reference", diagnostics: bool = False):
+                     diagnostics: bool = False):
     """One padded window through LP → round → repair → argmax → metrics,
     entirely in jnp.  ``u_cat (S·T, N, M)`` / ``u_phi (S·T, N, U, H)``
     carry ``n_seeds`` independent rounding seeds of ``best_of`` trials
-    each; the best trial *per seed* is selected on device.  ``backend``
-    picks the LP solver ("reference" or "pallas", see
-    ``repro.core.lp.LP_BACKENDS``) — decisions are identical either way.
+    each; the best trial *per seed* is selected on device.
     ``diagnostics=True`` adds the solver's residual/objective curves
     under ``"lp_diag"`` without changing any decision bit."""
     import jax
@@ -86,8 +85,7 @@ def _pipeline_kernel(data, u_cat, u_phi, iters, n_seeds,
     # the stages are named scopes, so that their operations carry the
     # stage's name in the compiled program and the profiler's trace
     with jax.named_scope("lp"):
-        lp_out = LP._lp_solve_kernel(data, iters, backend,
-                                     diagnostics=diagnostics)
+        lp_out = LP._pdhg_kernel(data, iters, diagnostics=diagnostics)
     x_f, A_f = lp_out[0], lp_out[1]
     with jax.named_scope("round"):
         x_r, A_r = round_from_uniforms(x_f, A_f, data.onehot_mu, u_cat,
@@ -115,14 +113,14 @@ def _pipeline_kernel(data, u_cat, u_phi, iters, n_seeds,
 
 
 @functools.cache
-def _pipeline_jitted(backend: str = "reference", diagnostics: bool = False):
+def _pipeline_jitted(diagnostics: bool = False):
     import jax
-    fn = jax.vmap(functools.partial(_pipeline_kernel, backend=backend,
+    fn = jax.vmap(functools.partial(_pipeline_kernel,
                                     diagnostics=diagnostics),
                   in_axes=(0, 0, 0, None, None))
     jitted = jax.jit(fn, static_argnums=(3, 4))
     return register_jit(
-        f"cocar:pipeline:{backend}:diag={int(bool(diagnostics))}", jitted)
+        f"cocar:pipeline:diag={int(bool(diagnostics))}", jitted)
 
 
 def offline_uniforms(stacked: StackedWindows, seed: int, n_seeds: int,
@@ -138,7 +136,6 @@ def offline_uniforms(stacked: StackedWindows, seed: int, n_seeds: int,
 
 def offline_pipeline_device(stacked: StackedWindows, u_cat, u_phi,
                             pdhg_iters: int = 4000, n_seeds: int = 1,
-                            lp_backend: str = "reference",
                             diagnostics: bool = False):
     """The whole offline grid in ONE jitted/vmapped f64 dispatch.
 
@@ -152,7 +149,7 @@ def offline_pipeline_device(stacked: StackedWindows, u_cat, u_phi,
     import jax
 
     with jax.enable_x64(True):
-        out = _pipeline_jitted(lp_backend, bool(diagnostics))(
+        out = _pipeline_jitted(bool(diagnostics))(
             stacked.data, u_cat, u_phi, int(pdhg_iters), int(n_seeds))
     return {k: ({kk: np.asarray(vv) for kk, vv in v.items()}
                 if isinstance(v, dict) else np.asarray(v))
@@ -219,7 +216,7 @@ def _eval_policy(data, x, A):
 
 def _policy_kernel(data, u_cat, u_phi, u_cat_s, u_phi_s, u_perm, u_h,
                    u_route, gat_params, gat_feats, gat_adj, iters, n_seeds,
-                   backend: str = "reference", diagnostics: bool = False):
+                   diagnostics: bool = False):
     """One padded window through ALL five policies, entirely in jnp.
 
     CoCaR runs the fused LP → round → repair → argmax pipeline
@@ -241,7 +238,7 @@ def _policy_kernel(data, u_cat, u_phi, u_cat_s, u_phi_s, u_perm, u_h,
     # (enforce is an identity post-repair, asserted in
     # tests/test_offline_batched.py), so the pipeline's own metrics stand
     coc = _pipeline_kernel(data, u_cat, u_phi, iters, n_seeds,
-                           backend=backend, diagnostics=diagnostics)
+                           diagnostics=diagnostics)
     out["cocar"] = {"x": coc["x"], "A": coc["A"], "metrics": coc["metrics"]}
     out["lp_obj"] = coc["lp_obj"]
     out["cocar_frac"] = {"x": coc["x_frac"], "A": coc["A_frac"]}
@@ -249,7 +246,7 @@ def _policy_kernel(data, u_cat, u_phi, u_cat_s, u_phi_s, u_perm, u_h,
         out["lp_diag"] = coc["lp_diag"]
 
     relaxed = BL.spr3_relax_device(data)
-    xs_f, As_f = LP._lp_solve_kernel(relaxed, iters, backend)
+    xs_f, As_f = LP._pdhg_kernel(relaxed, iters)
     xs_r, As_r = round_from_uniforms(xs_f, As_f, relaxed.onehot_mu,
                                      u_cat_s, u_phi_s)
     xs, As = jax.vmap(repair_device, in_axes=(None, 0, 0))(relaxed,
@@ -279,14 +276,14 @@ def _policy_kernel(data, u_cat, u_phi, u_cat_s, u_phi_s, u_perm, u_h,
 
 
 @functools.cache
-def _policy_jitted(backend: str = "reference", diagnostics: bool = False):
+def _policy_jitted(diagnostics: bool = False):
     import jax
-    fn = jax.vmap(functools.partial(_policy_kernel, backend=backend,
+    fn = jax.vmap(functools.partial(_policy_kernel,
                                     diagnostics=diagnostics),
                   in_axes=(0,) * 11 + (None, None))
     jitted = jax.jit(fn, static_argnums=(11, 12))
     return register_jit(
-        f"cocar:policy:{backend}:diag={int(bool(diagnostics))}", jitted)
+        f"cocar:policy:diag={int(bool(diagnostics))}", jitted)
 
 
 def policy_uniforms(stacked: StackedWindows, seed: int, n_seeds: int,
@@ -304,16 +301,13 @@ def policy_uniforms_dims(dims, seed, n_seeds: int, best_of: int):
     — same key splits, same draws.  The ``repro.scale`` executor draws
     these ONCE at the grid's global max shape and slices them per size
     bucket, so bucketed dispatches consume exactly the uniforms the
-    max-padded single dispatch would.  ``B=None`` drops the batch axis
-    and ``seed`` may be a PRNG key — the executor's ``per_element``
-    scheme draws one unbatched set per grid element that way."""
+    max-padded single dispatch would."""
     import jax
 
     from repro.core import baselines as BL
 
     B, N, M, U, H = dims
-    key = jax.random.PRNGKey(seed) if isinstance(seed, int) else seed
-    k_coc, k_spr, k_bl = jax.random.split(key, 3)
+    k_coc, k_spr, k_bl = jax.random.split(jax.random.PRNGKey(seed), 3)
     u_cat, u_phi = draw_rounding_uniforms(k_coc, n_seeds * max(best_of, 1),
                                           N, M, U, H, batch=B)
     u_cat_s, u_phi_s = draw_rounding_uniforms(k_spr, n_seeds, N, M, U, H,
@@ -347,7 +341,6 @@ def policy_grid_device(stacked: StackedWindows, seed: int = 0,
                        pdhg_iters: int = 4000, best_of: int = 8,
                        n_seeds: int = 1, episodes: int = 150,
                        uniforms=None, gat=None,
-                       lp_backend: str = "reference",
                        diagnostics: bool = False):
     """CoCaR + the four baselines over (windows × seeds) in ONE jitted/
     vmapped f64 dispatch (GatMARL training excepted — host-side, cached).
@@ -365,7 +358,7 @@ def policy_grid_device(stacked: StackedWindows, seed: int = 0,
         gat_grid_policies(stacked, seed, episodes)
     gat_params, gat_feats, gat_adj = gat
     with jax.enable_x64(True):
-        out = _policy_jitted(lp_backend, bool(diagnostics))(
+        out = _policy_jitted(bool(diagnostics))(
             stacked.data, *uniforms, gat_params, gat_feats, gat_adj,
             int(pdhg_iters), int(n_seeds))
 
@@ -495,52 +488,30 @@ def _unstack_device(stacked: StackedWindows, out, n_seeds: int):
 
 
 def cocar_grid(insts, seed: int = 0, pdhg_iters: int = 4000,
-               best_of: int = 8, n_seeds: int = 1, backend: str = "device",
-               devices: int = None, chunk_size: int = 0,
-               max_buckets: int = 1, lp_backend: str = "reference",
+               best_of: int = 8, n_seeds: int = 1, devices: int = None,
+               chunk_size: int = 0, max_buckets: int = 1,
                diagnostics: bool = False):
-    """CoCaR over a grid of independent windows × rounding seeds.
+    """CoCaR over a grid of independent windows × rounding seeds: the
+    fused LP → rounding → repair → metrics pipeline through the
+    ``repro.scale`` grid executor.  ``devices=None`` runs it on one
+    device; ``devices=k`` partitions the grid across a k-device host
+    mesh (decision-identical — see ``repro.scale.executor``).
+    ``chunk_size``/``max_buckets`` tune the executor's streaming chunk
+    and size-bucket count (the default ``max_buckets=1`` is the classic
+    one-padded-shape dispatch).  ``diagnostics`` threads the jit-safe
+    solver tap through.  Returns ``results[b][s] = (x, A, info)``."""
+    from repro.scale import GridSpec, run_grid
 
-    ``backend="device"``: the fused LP → rounding → repair → metrics
-    pipeline through the ``repro.scale`` grid executor on one device;
-    ``backend="sharded"``: the same executor partitioning the grid
-    across a ``devices``-wide host mesh (decision-identical — see
-    ``repro.scale.executor``).  ``devices``/``chunk_size``/``max_buckets``
-    tune the executor's mesh width, streaming chunk, and size-bucket
-    count (the default ``max_buckets=1`` is the classic one-padded-shape
-    dispatch).  ``backend="host"``: the NumPy reference — batched LP
-    dispatch, then per-(window, seed, trial) NumPy rounding + repair.
-    ``lp_backend`` independently picks the window LP solver ("reference"
-    or "pallas" — the fused mixed-precision kernel, decision-identical).
-    ``diagnostics`` threads the jit-safe solver tap through the device /
-    sharded executors (the host reference has no tap — it checks
-    feasibility directly).  Returns ``results[b][s] = (x, A, info)``.
-    """
-    insts = list(insts)
-    if backend in ("device", "sharded"):
-        from repro.scale import GridSpec, run_grid
-
-        spec = GridSpec(
-            kind="offline", insts=insts, seed=seed, n_seeds=n_seeds,
-            best_of=best_of, pdhg_iters=pdhg_iters,
-            backend="vmap" if backend == "device" else "sharded",
-            devices=devices, chunk_size=chunk_size,
-            max_buckets=max_buckets, lp_backend=lp_backend,
-            diagnostics=diagnostics)
-        return run_grid(spec).results
-    if backend != "host":
-        raise ValueError(f"unknown backend {backend!r}")
-    stacked = stack_instances(insts)
-    u_cat, u_phi = offline_uniforms(stacked, seed, n_seeds, best_of)
-    res = LP.solve_lp_pdhg_batched(stacked.data, iters=pdhg_iters,
-                                   backend=lp_backend)
-    return offline_pipeline_host(stacked, res.x, res.A, u_cat, u_phi,
-                                 n_seeds=n_seeds)
+    spec = GridSpec(
+        kind="offline", insts=list(insts), seed=seed, n_seeds=n_seeds,
+        best_of=best_of, pdhg_iters=pdhg_iters, devices=devices,
+        chunk_size=chunk_size, max_buckets=max_buckets,
+        diagnostics=diagnostics)
+    return run_grid(spec).results
 
 
 def cocar_windows_batched(insts, seed: int = 0, pdhg_iters: int = 4000,
-                          best_of: int = 8, backend: str = "device",
-                          lp_backend: str = "reference"):
+                          best_of: int = 8):
     """CoCaR over a stack of independent windows (scenario-grid variants,
     seeds, parallel traces) — one rounding seed per window, aligned with
     ``insts``.  Returns a list of (x, A, info) triples.
@@ -549,8 +520,7 @@ def cocar_windows_batched(insts, seed: int = 0, pdhg_iters: int = 4000,
     but must share the catalog shape (M, H).
     """
     grid = cocar_grid(insts, seed=seed, pdhg_iters=pdhg_iters,
-                      best_of=best_of, n_seeds=1, backend=backend,
-                      lp_backend=lp_backend)
+                      best_of=best_of, n_seeds=1)
     return [per_seed[0] for per_seed in grid]
 
 

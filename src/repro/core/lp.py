@@ -344,58 +344,28 @@ def _pdhg_kernel(data: PDHGData, iters: int, diagnostics: bool = False,
     return x, jnp.swapaxes(A, 1, 2), diag
 
 
-#: LP solver backends: "reference" is the plain f64 kernel above;
-#: "pallas" is the fused mixed-precision path (repro.kernels.pdhg_fused
-#: — the Pallas engine on TPU, its lax.scan realization elsewhere).
-LP_BACKENDS = ("reference", "pallas")
-
-
-def _lp_solve_kernel(data, iters: int, backend: str = "reference",
-                     diagnostics: bool = False,
-                     diag_stride: int = DIAG_STRIDE):
-    """Traceable (x, A) window solve dispatching on ``backend``.  Both
-    backends return float64 x (N,M,H+1) / A (N,U,H); "pallas" produces
-    fractionals within rounding-margin of the reference, so downstream
-    decisions (rounding, repair, winning trials) are identical — the
-    contract tests/test_pdhg_fused.py enforces.
-
-    ``diagnostics=True`` appends a jit-safe curves pytree as a third
-    return (see ``_pdhg_kernel``); the decision arrays are bit-identical
-    either way (tests/test_obs.py)."""
-    if backend == "reference":
-        return _pdhg_kernel(data, iters, diagnostics=diagnostics,
-                            diag_stride=diag_stride)
-    if backend == "pallas":
-        from repro.kernels.pdhg_fused import pdhg_fused
-        return pdhg_fused(data, iters, diagnostics=diagnostics,
-                          diag_stride=diag_stride)
-    raise ValueError(f"unknown LP backend {backend!r}; one of {LP_BACKENDS}")
-
-
 _JIT_CACHE = {}
 
 
-def _jitted_kernel(batched: bool, backend: str = "reference",
-                   diagnostics: bool = False,
+def _jitted_kernel(batched: bool, diagnostics: bool = False,
                    diag_stride: int = DIAG_STRIDE):
-    """Module-level jit cache: one compile per (batched, backend, diag,
-    shape, iters) — repeat calls at the same shapes (e.g. window loops)
-    skip tracing.  Every cached entry point is registered with
-    ``repro.obs`` so span retrace counters see it."""
+    """Module-level jit cache: one compile per (batched, diag, shape,
+    iters) — repeat calls at the same shapes (e.g. window loops) skip
+    tracing.  Every cached entry point is registered with ``repro.obs``
+    so span retrace counters see it."""
     mode = "batched" if batched else "single"
     # the stride is only a trace constant when the tap is on; normalize
     # it out of the key otherwise so diag-off callers share one compile
-    key = (mode, backend, bool(diagnostics),
+    key = (mode, bool(diagnostics),
            int(diag_stride) if diagnostics else None)
     if key not in _JIT_CACHE:
         import jax
-        fn = functools.partial(_lp_solve_kernel, backend=backend,
-                               diagnostics=diagnostics,
+        fn = functools.partial(_pdhg_kernel, diagnostics=diagnostics,
                                diag_stride=diag_stride)
         if batched:
             fn = jax.vmap(fn, in_axes=(0, None))
         jitted = jax.jit(fn, static_argnums=(1,))
-        name = f"lp:{mode}:{backend}:diag={int(bool(diagnostics))}"
+        name = f"lp:{mode}:diag={int(bool(diagnostics))}"
         _JIT_CACHE[key] = register_jit(name, jitted)
     return _JIT_CACHE[key]
 
@@ -442,15 +412,13 @@ def pdhg_primal_residual(inst: JDCRInstance, x, A) -> float:
 
 
 def solve_lp_pdhg(inst: JDCRInstance, iters: int = 4000, check_every: int = 200,
-                  tol: float = PDHG_TOL, backend: str = "reference",
-                  diagnostics: bool = False):
+                  tol: float = PDHG_TOL, diagnostics: bool = False):
     """One-window PDHG solve.  The result always carries a ``converged``
     flag (final residual vs ``tol``) instead of silently returning after
     the fixed iteration budget; ``diagnostics=True`` additionally attaches
     the device-sampled residual/objective curves (stride =
     ``check_every``) without changing x/A bits."""
-    out = _jitted_kernel(batched=False, backend=backend,
-                         diagnostics=diagnostics,
+    out = _jitted_kernel(batched=False, diagnostics=diagnostics,
                          diag_stride=check_every)(pdhg_data(inst), iters)
     x, A = out[0], out[1]
     diag = ({k: np.asarray(v) for k, v in out[2].items()}
@@ -466,7 +434,6 @@ def solve_lp_pdhg(inst: JDCRInstance, iters: int = 4000, check_every: int = 200,
 
 
 def solve_lp_pdhg_batched(data: PDHGData, iters: int = 4000,
-                          backend: str = "reference",
                           diagnostics: bool = False,
                           diag_stride: int = DIAG_STRIDE) -> BatchedPDHGResult:
     """Solve a whole stack of windows in ONE vmapped, jitted dispatch.
@@ -477,8 +444,7 @@ def solve_lp_pdhg_batched(data: PDHGData, iters: int = 4000,
     base stations hold A == 0 throughout (``bs_mask``), so padding
     contributes nothing to the einsum.
     """
-    out = _jitted_kernel(batched=True, backend=backend,
-                         diagnostics=diagnostics,
+    out = _jitted_kernel(batched=True, diagnostics=diagnostics,
                          diag_stride=diag_stride)(data, iters)
     x, A = out[0], out[1]
     diag = ({k: np.asarray(v) for k, v in out[2].items()}

@@ -9,8 +9,6 @@ crossed with ``n_seeds`` independent rounding seeds — in ONE jitted/
 vmapped device dispatch (``repro.core.cocar.cocar_grid``), emitting one
 flat results table: a list of row dicts, each carrying the swept axis
 values, the LP objective, and the post-repair window metrics.
-``backend="host"`` keeps the NumPy round+repair loop (the reference
-path) behind the same interface.
 
 Online: ``run_online_sweep`` crosses config variants with *workload
 families* (``repro.traces.make_workload``: flash crowds, diurnal load,
@@ -26,10 +24,10 @@ the other paper tables; run standalone with
     PYTHONPATH=src python -m repro.experiments.sweep            # offline
     PYTHONPATH=src python -m repro.experiments.sweep --online   # online
 
-``--shard`` partitions any of the grids across a device mesh via the
-``repro.scale`` executor (the chips of a TPU host; on a CPU run under
-``XLA_FLAGS=--xla_force_host_platform_device_count=K``); ``--devices``
-and ``--chunk`` tune the mesh width and streaming chunk.
+``--devices N`` partitions any of the grids across an N-device mesh via
+the ``repro.scale`` executor (the chips of a TPU host; on a CPU run
+under ``XLA_FLAGS=--xla_force_host_platform_device_count=N``);
+``--chunk`` tunes the streaming chunk.
 
 Observability (``repro.obs``): diagnostics taps are ON by default —
 PDHG residual/convergence columns on offline rows, per-slot cache
@@ -69,15 +67,14 @@ DEFAULT_AXES = {
 
 def run_sweep(base: MECConfig = None, axes: dict = None, window: int = 0,
               pdhg_iters: int = 4000, best_of: int = 8, seed: int = 0,
-              n_seeds: int = 1, backend: str = "device",
-              devices: int = None, chunk_size: int = 0,
+              n_seeds: int = 1, devices: int = None, chunk_size: int = 0,
               max_buckets: int = 1, diagnostics: bool = False):
     """One CoCaR window per (grid variant × rounding seed), the whole grid
     as ONE fused device dispatch — LP, rounding, repair, trial argmax and
     window metrics all inside the jit (mirroring the ``--online`` grid).
-    ``backend="sharded"`` (the ``--shard`` flag) partitions the grid
-    across a host-device mesh via ``repro.scale`` — decision-identical,
-    just spread over ``devices`` devices in ``chunk_size`` streams.
+    ``devices=k`` (the ``--devices`` flag) partitions the grid across a
+    k-device host mesh via ``repro.scale`` — decision-identical, just
+    spread over the devices in ``chunk_size`` streams.
     ``max_buckets > 1`` opts heterogeneous grids into size-bucketed
     padding (still decision-identical; only the reported ``lp_obj``
     carries ~1e-14 reduction-order slack).
@@ -85,8 +82,7 @@ def run_sweep(base: MECConfig = None, axes: dict = None, window: int = 0,
     ``diagnostics=True`` taps the PDHG solver's residual curves inside
     the jit (``repro.obs``) and adds ``pdhg_final_residual`` /
     ``pdhg_converged`` columns to every row — decisions stay bit-
-    identical (device/sharded backends only; the host reference loop
-    has no tap).
+    identical.
 
     Returns a list of row dicts (variant-major, seed-minor, in grid
     order); with ``n_seeds > 1`` each row carries its ``rounding_seed``.
@@ -97,7 +93,7 @@ def run_sweep(base: MECConfig = None, axes: dict = None, window: int = 0,
     scenarios = [Scenario(c) for c in cfgs]
     insts = [sc.instance(window, sc.empty_cache()) for sc in scenarios]
     grid = cocar_grid(insts, seed=seed, pdhg_iters=pdhg_iters,
-                      best_of=best_of, n_seeds=n_seeds, backend=backend,
+                      best_of=best_of, n_seeds=n_seeds,
                       devices=devices, chunk_size=chunk_size,
                       max_buckets=max_buckets, diagnostics=diagnostics)
     rows = []
@@ -119,28 +115,24 @@ def run_sweep(base: MECConfig = None, axes: dict = None, window: int = 0,
 def run_policy_sweep(base: MECConfig = None, axes: dict = None,
                      window: int = 0, pdhg_iters: int = 4000,
                      best_of: int = 8, seed: int = 0, n_seeds: int = 1,
-                     episodes: int = 60, backend: str = "device",
-                     devices: int = None, chunk_size: int = 0,
-                     max_buckets: int = 1, diagnostics: bool = False):
+                     episodes: int = 60, devices: int = None,
+                     chunk_size: int = 0, max_buckets: int = 1,
+                     diagnostics: bool = False):
     """The paper's Sec. VII-B headline comparison — CoCaR vs SPR³ /
     Greedy / Random / GatMARL — across (grid variants × rounding seeds ×
     policies), every policy's decisions AND the shared evaluation stage in
     ONE fused device dispatch (GatMARL training excepted: host-side,
     cached per topology).
 
-    ``diagnostics=True`` (device/sharded only) taps the CoCaR LP's PDHG
-    residuals per window and attaches a ``summary["convergence"]`` table
-    over the grid; decisions stay bit-identical.
+    ``diagnostics=True`` taps the CoCaR LP's PDHG residuals per window
+    and attaches a ``summary["convergence"]`` table over the grid;
+    decisions stay bit-identical.
 
     Returns ``(rows, summary)``: one row dict per (variant, seed, policy)
     plus a summary with per-policy grid means and the CoCaR-vs-best-
     baseline improvement ratio.
     """
-    from repro.core.baselines import spr3_relaxed
-    from repro.core.cocar import (gat_grid_policies, policy_grid_host,
-                                  policy_uniforms)
-    from repro.core.lp import solve_lp_pdhg_batched
-    from repro.mec.scenario import stack_instances
+    from repro.scale import GridSpec, run_grid
 
     base = base or MECConfig(n_users=40)
     axes = axes or DEFAULT_AXES
@@ -148,32 +140,13 @@ def run_policy_sweep(base: MECConfig = None, axes: dict = None,
     scenarios = [Scenario(c) for c in cfgs]
     insts = [sc.instance(window, sc.empty_cache()) for sc in scenarios]
 
-    lp_diag = None
-    if backend in ("device", "sharded"):
-        from repro.scale import GridSpec, run_grid
-
-        gr = run_grid(GridSpec(
-            kind="policy", insts=insts, seed=seed, n_seeds=n_seeds,
-            best_of=best_of, pdhg_iters=pdhg_iters, episodes=episodes,
-            backend="vmap" if backend == "device" else "sharded",
-            devices=devices, chunk_size=chunk_size,
-            max_buckets=max_buckets, diagnostics=diagnostics))
-        res = gr.results
-        lp_diag = gr.stats.get("lp_diag")
-        met = _policy_met(res, len(insts), n_seeds)
-    elif backend == "host":
-        stacked = stack_instances(insts)
-        uniforms = policy_uniforms(stacked, seed, n_seeds, best_of)
-        gat = gat_grid_policies(stacked, seed, episodes)
-        res = solve_lp_pdhg_batched(stacked.data, iters=pdhg_iters)
-        relaxed = stack_instances([spr3_relaxed(i) for i in insts])
-        res_s = solve_lp_pdhg_batched(relaxed.data, iters=pdhg_iters)
-        host = policy_grid_host(stacked, uniforms, gat, res.x, res.A,
-                                {"x": res_s.x, "A": res_s.A},
-                                n_seeds=n_seeds)
-        met = _policy_met(host, len(stacked), n_seeds)
-    else:
-        raise ValueError(f"unknown backend {backend!r}")
+    gr = run_grid(GridSpec(
+        kind="policy", insts=insts, seed=seed, n_seeds=n_seeds,
+        best_of=best_of, pdhg_iters=pdhg_iters, episodes=episodes,
+        devices=devices, chunk_size=chunk_size, max_buckets=max_buckets,
+        diagnostics=diagnostics))
+    lp_diag = gr.stats.get("lp_diag")
+    met = _policy_met(gr.results, len(insts), n_seeds)
     rows, summary = _policy_rows(cfgs, axes, met, n_seeds)
     if lp_diag:
         summary["convergence"] = convergence_table(
@@ -228,12 +201,12 @@ DEFAULT_POLICIES = ("cocar-ol", "lfu")
 
 def run_online_sweep(base: MECConfig = None, axes: dict = None,
                      workloads=None, policies=DEFAULT_POLICIES,
-                     ocfg=None, seed: int = 0, backend: str = "vmap",
-                     devices: int = None, chunk_size: int = 0,
-                     diagnostics: bool = False, registry=None):
+                     ocfg=None, seed: int = 0, devices: int = None,
+                     chunk_size: int = 0, diagnostics: bool = False,
+                     registry=None):
     """Cross (config grid x workload family x policy), run everything in
-    one vmapped scan dispatch (``backend="sharded"`` spreads it across a
-    host-device mesh).  ``workloads`` names registry families
+    one vmapped scan dispatch (``devices=k`` spreads it across a k-device
+    host mesh).  ``workloads`` names registry families
     (``repro.traces.make_workload`` — per-user traces and the streaming
     ``poisson_zipf`` family alike; all flow through the unified
     aggregated-demand engine).  ``diagnostics=True`` taps the per-slot
@@ -262,9 +235,8 @@ def run_online_sweep(base: MECConfig = None, axes: dict = None,
                 jobs.append(dict(cfg=cfg, algo=algo, workload=wl,
                                  seed=seed))
                 keys.append((cfg, wl, algo))
-    results = run_online_grid(jobs, ocfg, backend=backend,
-                              devices=devices, chunk_size=chunk_size,
-                              diagnostics=diagnostics)
+    results = run_online_grid(jobs, ocfg, devices=devices,
+                              chunk_size=chunk_size, diagnostics=diagnostics)
     rows = []
     for (cfg, wl, algo), res in zip(keys, results):
         row = {k: getattr(cfg, k) for k in axes}
@@ -305,18 +277,18 @@ SMOKE_AXES = {"zipf": (0.4, 0.8)}
 SMOKE_ITERS = 3000
 
 
-def main(online: bool = False, backend: str = "device", n_seeds: int = 1,
+def main(online: bool = False, n_seeds: int = 1,
          policies: bool = False, devices: int = None, chunk_size: int = 0,
          max_buckets: int = 1, diagnostics: bool = True,
          smoke: bool = False):
     payload, registry = None, None
     kind = "online" if online else "policy" if policies else "offline"
     out = pathlib.Path("results") / "sweep" / ("ci" if smoke else "")
-    with TRACER.span("sweep", kind=kind, backend=backend, smoke=smoke,
+    with TRACER.span("sweep", kind=kind, devices=devices, smoke=smoke,
                      diagnostics=diagnostics):
         if smoke:
             rows = run_sweep(base=MECConfig(n_users=20), axes=SMOKE_AXES,
-                             pdhg_iters=SMOKE_ITERS, backend=backend,
+                             pdhg_iters=SMOKE_ITERS,
                              n_seeds=n_seeds, devices=devices,
                              chunk_size=chunk_size,
                              diagnostics=diagnostics)
@@ -326,13 +298,11 @@ def main(online: bool = False, backend: str = "device", n_seeds: int = 1,
 
             registry = MetricsRegistry() if diagnostics else None
             rows = run_online_sweep(
-                backend="sharded" if backend == "sharded" else "vmap",
                 devices=devices, chunk_size=chunk_size,
                 diagnostics=diagnostics, registry=registry)
             name = "online_grid.json"
         elif policies:
-            rows, summary = run_policy_sweep(backend=backend,
-                                             n_seeds=n_seeds,
+            rows, summary = run_policy_sweep(n_seeds=n_seeds,
                                              devices=devices,
                                              chunk_size=chunk_size,
                                              max_buckets=max_buckets,
@@ -340,7 +310,7 @@ def main(online: bool = False, backend: str = "device", n_seeds: int = 1,
             name = "policy_grid.json"
             payload = {"rows": rows, "summary": summary}
         else:
-            rows = run_sweep(backend=backend, n_seeds=n_seeds,
+            rows = run_sweep(n_seeds=n_seeds,
                              devices=devices, chunk_size=chunk_size,
                              max_buckets=max_buckets,
                              diagnostics=diagnostics)
@@ -351,7 +321,7 @@ def main(online: bool = False, backend: str = "device", n_seeds: int = 1,
     path.write_text(json.dumps(payload if payload is not None else rows,
                                indent=1, default=float))
     write_manifest(path,
-                   config=dict(kind=kind, backend=backend,
+                   config=dict(kind=kind,
                                n_seeds=n_seeds, devices=devices,
                                chunk_size=chunk_size,
                                max_buckets=max_buckets,
@@ -371,7 +341,7 @@ def main(online: bool = False, backend: str = "device", n_seeds: int = 1,
             print(f"pdhg convergence: "
                   f"{c['n_windows'] - c['n_not_converged']}/"
                   f"{c['n_windows']} windows <= tol {c['tol']:g}")
-    elif diagnostics and not online and backend != "host":
+    elif diagnostics and not online:
         bad = sum(1 for r in rows if not r.get("pdhg_converged", True))
         print(f"\npdhg convergence: {len(rows) - bad}/{len(rows)} "
               f"windows converged")
@@ -391,15 +361,12 @@ if __name__ == "__main__":
     ap.add_argument("--policies", action="store_true",
                     help="CoCaR vs the Sec. VII-B baseline zoo, one "
                          "dispatch across (variants x seeds x policies)")
-    ap.add_argument("--host", action="store_true",
-                    help="NumPy round+repair reference loop")
-    ap.add_argument("--shard", action="store_true",
-                    help="partition the grid across a device mesh "
-                         "(repro.scale; the chips of a TPU host, or on a "
-                         "CPU K virtual devices under XLA_FLAGS="
-                         "--xla_force_host_platform_device_count=K)")
     ap.add_argument("--devices", type=int, default=None,
-                    help="mesh width for --shard (default: all devices)")
+                    help="partition the grid across a mesh of N devices "
+                         "(repro.scale; the chips of a TPU host, or on a "
+                         "CPU N virtual devices under XLA_FLAGS="
+                         "--xla_force_host_platform_device_count=N); "
+                         "default: one device, no mesh")
     ap.add_argument("--chunk", type=int, default=0,
                     help="streaming chunk size (0 = one chunk per bucket)")
     ap.add_argument("--buckets", type=int, default=1,
@@ -414,18 +381,10 @@ if __name__ == "__main__":
                     help="compile the solver/scan diagnostics taps out "
                          "(decisions are bit-identical either way)")
     args = ap.parse_args()
-    if args.host and args.shard:
-        ap.error("--host and --shard are mutually exclusive")
-    if args.devices is not None and not args.shard:
-        ap.error("--devices requires --shard (a plain run would "
-                 "silently ignore it)")
-    if args.smoke and (args.online or args.policies or args.host):
-        ap.error("--smoke is an offline device/sharded grid; it takes "
-                 "none of --online/--policies/--host")
-    main(online=args.online,
-         backend=("host" if args.host
-                  else "sharded" if args.shard else "device"),
-         n_seeds=args.seeds, policies=args.policies,
+    if args.smoke and (args.online or args.policies):
+        ap.error("--smoke is an offline grid; it takes neither --online "
+                 "nor --policies")
+    main(online=args.online, n_seeds=args.seeds, policies=args.policies,
          devices=args.devices, chunk_size=args.chunk,
          max_buckets=args.buckets, diagnostics=not args.no_diag,
          smoke=args.smoke)

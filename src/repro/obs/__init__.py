@@ -2,7 +2,7 @@
 retrace accounting, run manifests, streaming metrics, per-request
 events, and jit-safe solver/engine diagnostics summaries.
 
-Five parts (docs/algorithms.md Sec. 11 and 14):
+Five parts (docs/algorithms.md Sec. 10 and 13):
 
   * :mod:`repro.obs.tracing` — ``Span``/``Tracer`` built on the
     monotonic ``time.perf_counter``, with thread CPU time per span, the
@@ -15,8 +15,8 @@ Five parts (docs/algorithms.md Sec. 11 and 14):
     to every emitted results file;
   * :mod:`repro.obs.diagnostics` — host-side summaries of the jit-safe
     diagnostics pytrees the kernels emit (``diagnostics=True`` through
-    ``repro.core.lp``, ``repro.kernels.pdhg_fused``,
-    ``repro.traces.engine`` and the ``repro.scale`` executor);
+    ``repro.core.lp``, ``repro.traces.engine`` and the ``repro.scale``
+    executor);
   * :mod:`repro.obs.metrics` — mergeable fixed-bucket streaming
     histograms, counters, gauges; Prometheus-textfile + JSON exporters;
     adapters folding QueueSim runs and online engine telemetry into one

@@ -1,9 +1,8 @@
 """Host-side summaries of the jit-safe diagnostics pytrees.
 
-The kernels (``repro.core.lp``, ``repro.kernels.pdhg_fused``,
-``repro.traces.engine``) emit raw device curves — residuals, objective
-trajectories, per-slot cache stats — sampled every ``diag_stride``
-iterations.  This module turns those curves into the JSON-safe
+The kernels (``repro.core.lp``, ``repro.traces.engine``) emit raw
+device curves — residuals, objective trajectories, per-slot cache
+stats — sampled every ``diag_stride`` iterations.  This module turns those curves into the JSON-safe
 convergence records that sweeps, benches, ``scripts/report.py`` and
 ``check_bench.py`` consume.  Pure numpy/stdlib; imports no jax.
 
@@ -33,8 +32,8 @@ def lp_diag_summary(diag, tol: float = DEFAULT_TOL) -> dict:
     ``iters_to_tol`` (first *sampled* iteration whose primal residual
     is <= tol, -1 if never — the curve is sampled at ``diag_stride``,
     so this is an upper bound on the true crossing), ``tol`` and
-    ``n_samples``.  Curves that exist in the pytree (``polish_delta``,
-    final objective) are passed through.
+    ``n_samples``, plus the final dual residual and objective where the
+    pytree has those curves.
     """
     pr = _to_np(diag["primal_res"]).ravel()
     iters = _to_np(diag["iters"]).ravel()
@@ -55,8 +54,6 @@ def lp_diag_summary(diag, tol: float = DEFAULT_TOL) -> dict:
         ob = _to_np(diag["obj"]).ravel()
         if ob.size:
             out["final_obj"] = float(ob[-1])
-    if "polish_delta" in diag:
-        out["polish_delta"] = float(_to_np(diag["polish_delta"]))
     return out
 
 

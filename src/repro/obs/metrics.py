@@ -2,7 +2,7 @@
 gauges, and the exporters that turn one serving/sweep run into a
 Prometheus textfile plus a JSON snapshot.
 
-Design constraints (docs/algorithms.md Sec. 14):
+Design constraints (docs/algorithms.md Sec. 13):
 
   * **Fixed buckets, mergeable.** A :class:`Histogram` owns an immutable
     tuple of upper bucket edges chosen at construction.  Observations

@@ -10,8 +10,7 @@ mapping.
 """
 from repro.scale.buckets import Bucket, BucketPlan, plan_buckets
 from repro.scale.executor import (GridResult, GridSpec,
-                                  compiled_cache_stats, grid_mesh,
-                                  run_grid)
+                                  compiled_cache_stats, run_grid)
 
 __all__ = ["Bucket", "BucketPlan", "plan_buckets", "GridResult",
-           "GridSpec", "compiled_cache_stats", "grid_mesh", "run_grid"]
+           "GridSpec", "compiled_cache_stats", "run_grid"]

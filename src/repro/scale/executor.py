@@ -22,31 +22,23 @@ it through three composable layers:
      memory is O(chunk), not O(grid), as grids grow to thousands of
      scenarios.
 
-Decision identity — the PR-3/PR-4 dual-engine discipline, now host-vmap
-vs sharded — is engineered, not hoped for.  Padded rows are exactly
-inert in every kernel, and the rounding/baseline randomness comes from
-one of two schemes (``GridSpec.rng``), each invariant to the execution
-layout:
+The layout is chosen by ``GridSpec.devices`` alone: ``None`` (the
+default) vmaps each chunk on one device with no mesh; ``k`` runs the
+same vmapped kernel under ``shard_map`` over a k-device mesh (``k=1``
+included).
 
-  * ``"stacked"`` (default): drawn ONCE at the grid's global max shape
-    — exactly the tensors the single-dispatch path consumes — and
-    *sliced* per bucket, so the executor is bit-compatible with the
-    legacy one-device dispatch.  The draw itself is O(grid) host bytes;
-    right for grids whose uniforms fit in host RAM.
-  * ``"per_element"``: one ``fold_in(seed, grid_index)`` key per
-    element, drawn lazily per chunk at the global max shape and sliced
-    — O(chunk) bytes end to end, and invariant to bucketing/chunking/
-    sharding by construction (different numbers than ``"stacked"``, but
-    self-consistent across every layout).  Use it when the grid scales
-    past host RAM.
+Decision identity across layouts is engineered, not hoped for.  Padded
+rows are exactly inert in every kernel, and the rounding/baseline
+randomness is drawn ONCE at the grid's global max shape — exactly the
+tensors the single-dispatch path consumes — and *sliced* per bucket, so
+the executor is bit-compatible with the legacy one-device dispatch.
+Any (bucketing × chunking × mesh) combination therefore reproduces the
+same cache/routing arrays and winning trials bit-identically (asserted
+in ``tests/test_scale.py`` and gated by ``benchmarks/bench_scale.py`` →
+``scripts/check_bench.py``).
 
-Under either scheme, any (bucketing × chunking × backend) combination
-reproduces the same cache/routing arrays and winning trials
-bit-identically (asserted in ``tests/test_scale.py`` and gated by
-``benchmarks/bench_scale.py`` → ``scripts/check_bench.py``).
-
-Compiled executables are cached module-level, keyed on (kind, backend,
-mesh, static knobs); chunk shapes are padded to full chunks, so a whole
+Compiled executables are cached module-level, keyed on (kind, mesh,
+static knobs); chunk shapes are padded to full chunks, so a whole
 sweep compiles once per (bucket shape, chunk) and repeated sweeps with
 the same :class:`~repro.scale.buckets.BucketPlan` key retrace nothing.
 """
@@ -70,10 +62,10 @@ class GridSpec:
     ``kind`` selects the kernel family: ``"offline"`` (fused LP → round
     → repair → metrics over ``insts``), ``"policy"`` (all five offline
     policies over ``insts``), ``"online"`` (the scan engine over
-    ``jobs`` + ``ocfg``).  ``backend="sharded"`` partitions each chunk
-    across ``devices`` mesh devices; ``backend="vmap"`` runs the
-    identical bucketed/chunked schedule on one device (the equivalence
-    reference, and the sensible default when only one device exists).
+    ``jobs`` + ``ocfg``).  ``devices=None`` runs the bucketed/chunked
+    schedule vmapped on one device with no mesh; ``devices=k``
+    partitions each chunk across a k-device mesh with ``shard_map``
+    (decision-identical to the no-mesh run).
     """
     kind: str
     insts: list = None           # offline / policy kinds
@@ -83,14 +75,11 @@ class GridSpec:
     n_seeds: int = 1             # offline/policy: rounding seeds
     best_of: int = 8
     pdhg_iters: int = 4000
-    lp_backend: str = "reference"  # window LP solver ("reference"|"pallas")
     episodes: int = 150          # policy: GatMARL training budget
-    backend: str = "sharded"     # "sharded" | "vmap"
-    devices: int = None          # mesh size; None = all visible devices
+    devices: int = None          # mesh size; None = one device, no mesh
     chunk_size: int = 0          # batch per dispatch; 0 = one chunk/bucket
     max_buckets: int = 4
     round_users_to: int = 1
-    rng: str = "stacked"         # uniform-draw scheme, see run_grid
     progress: object = None      # callable(dict) per finished chunk
     diagnostics: bool = False    # jit-safe solver/engine telemetry tap
 
@@ -107,17 +96,6 @@ class GridResult:
 # ---------------------------------------------------------------------------
 # mesh + compiled-executable cache
 # ---------------------------------------------------------------------------
-
-def grid_mesh(devices: int = None):
-    """A ("data", "model") host mesh with ``devices`` data shards (all
-    visible devices by default) — ``launch.mesh.make_host_mesh`` with
-    its device-count validation."""
-    import jax
-
-    from repro.launch.mesh import make_host_mesh
-
-    return make_host_mesh(data=int(devices or len(jax.devices())), model=1)
-
 
 _COMPILED = {}
 
@@ -182,10 +160,7 @@ def _run_chunks(spec: GridSpec, mesh, fn, args, B: int, stats: dict,
                 bucket_key=None):
     """Stream ``args`` through ``fn`` in fixed-size chunks; returns
     outputs concatenated back to batch size B (as host numpy).  ``args``
-    is either a tuple of pytrees with a leading batch axis of size B, or
-    a callable ``make(take) -> tuple`` that materializes one chunk's
-    arguments on demand (how the ``per_element`` RNG mode keeps even the
-    uniform draws at O(chunk)).
+    is a tuple of pytrees with a leading batch axis of size B.
 
     Every chunk is padded to the full chunk size by repeating element 0
     (one compiled shape per bucket; the pad rows are sliced off), its
@@ -202,8 +177,6 @@ def _run_chunks(spec: GridSpec, mesh, fn, args, B: int, stats: dict,
     diagnostics are off it is never called."""
     import jax
 
-    make = args if callable(args) else \
-        (lambda take: tuple(_take_rows(a, take) for a in args))
     D = 1 if mesh is None else int(mesh.devices.size)
     chunk = int(spec.chunk_size) if spec.chunk_size else B
     chunk = -(-max(chunk, 1) // D) * D            # round up to mesh multiple
@@ -220,11 +193,11 @@ def _run_chunks(spec: GridSpec, mesh, fn, args, B: int, stats: dict,
         if len(take) < chunk:                     # pad the tail chunk
             take = np.concatenate(
                 [take, np.zeros(chunk - len(take), dtype=int)])
-        if chunk == B and not callable(args):
+        if chunk == B:
             chunk_args = args                     # whole grid, one chunk:
         else:                                     # no identity row-copy
             with OT.TRACER.span("grid.prepare", count_retraces=False):
-                chunk_args = make(take)
+                chunk_args = tuple(_take_rows(a, take) for a in args)
         in_bytes = sum(_nbytes(a) for a in chunk_args)
         pad_rows = int(chunk - (min(start + chunk, B) - start))
         with OT.TRACER.span("chunk", kind=spec.kind,
@@ -301,31 +274,13 @@ def _fit_axes(arr, *dims):
 
 
 def _mesh_of(spec: GridSpec):
-    if spec.backend == "sharded":
-        return grid_mesh(spec.devices)
-    if spec.backend != "vmap":
-        raise ValueError(f"unknown backend {spec.backend!r}; "
-                         "one of ('sharded', 'vmap')")
-    if spec.devices:
-        raise ValueError(
-            f"spec.devices={spec.devices} is only meaningful with "
-            "backend='sharded' — a vmap run would silently ignore it")
-    return None
+    """No mesh for ``devices=None``; else a ``devices``-wide mesh, whose
+    width ``make_host_mesh`` validates."""
+    if spec.devices is None:
+        return None
+    from repro.launch.mesh import make_host_mesh
 
-
-def _element_key(seed, index):
-    """The ``per_element`` RNG scheme: one PRNG key per original grid
-    index, independent of bucketing/chunking/sharding by construction."""
-    import jax
-
-    with jax.enable_x64(True):
-        return jax.random.fold_in(jax.random.PRNGKey(seed), int(index))
-
-
-def _check_rng(spec: GridSpec):
-    if spec.rng not in ("stacked", "per_element"):
-        raise ValueError(f"unknown rng scheme {spec.rng!r}; "
-                         "one of ('stacked', 'per_element')")
+    return make_host_mesh(data=int(spec.devices), model=1)
 
 
 # ---------------------------------------------------------------------------
@@ -347,10 +302,9 @@ def _run_offline(spec: GridSpec, mesh, stats):
                             round_users_to=spec.round_users_to)
         stats["plan"] = plan.key
         S, T = int(spec.n_seeds), max(int(spec.best_of), 1)
-        if spec.rng == "stacked":
-            # the tensors offline_uniforms draws for the max-padded stack
-            u_cat, u_phi = draw_rounding_uniforms(spec.seed, S * T, N_g, M,
-                                                  U_g, H, batch=B)
+        # the tensors offline_uniforms draws for the max-padded stack
+        u_cat, u_phi = draw_rounding_uniforms(spec.seed, S * T, N_g, M,
+                                              U_g, H, batch=B)
 
     results = [None] * B
     for bucket in plan.buckets:
@@ -359,24 +313,11 @@ def _run_offline(spec: GridSpec, mesh, stats):
         with OT.TRACER.span("grid.prepare", count_retraces=False):
             stacked = stack_instances([insts[i] for i in idx],
                                       pad_to=(Nb, Ub))
-            if spec.rng == "stacked":
-                args = (stacked.data,
-                        _fit_axes(u_cat[idx], (2, Nb)),
-                        _fit_axes(u_phi[idx], (2, Nb), (3, Ub)))
-            else:
-                def args(take, idx=idx, data=stacked.data, Nb=Nb, Ub=Ub):
-                    ucs, ups = zip(*(
-                        draw_rounding_uniforms(
-                            _element_key(spec.seed, idx[j]), S * T, N_g, M,
-                            U_g, H)
-                        for j in take))
-                    return (_take_rows(data, take),
-                            np.stack([_fit_axes(u, (1, Nb)) for u in ucs]),
-                            np.stack([_fit_axes(u, (1, Nb), (2, Ub))
-                                      for u in ups]))
+            args = (stacked.data,
+                    _fit_axes(u_cat[idx], (2, Nb)),
+                    _fit_axes(u_phi[idx], (2, Nb), (3, Ub)))
         fn = _compile("offline", mesh, 3, _offline_inner(spec),
-                      int(spec.pdhg_iters), S, spec.lp_backend,
-                      bool(spec.diagnostics))
+                      int(spec.pdhg_iters), S, bool(spec.diagnostics))
         out = _run_chunks(spec, mesh, fn, args,
                           len(idx), stats, bucket_key=bucket.key)
         with OT.TRACER.span("grid.unstack", count_retraces=False):
@@ -393,11 +334,9 @@ def _offline_inner(spec: GridSpec):
         from repro.core.cocar import _pipeline_kernel
 
         iters, n_seeds = int(spec.pdhg_iters), int(spec.n_seeds)
-        lp_backend = spec.lp_backend
         diagnostics = bool(spec.diagnostics)
         def cocar_offline_pipeline(d, uc, up):
             return _pipeline_kernel(d, uc, up, iters, n_seeds,
-                                    backend=lp_backend,
                                     diagnostics=diagnostics)
 
         # a named function, so that the compiled executable is named
@@ -423,15 +362,13 @@ def _run_policy(spec: GridSpec, mesh, stats):
                         round_users_to=spec.round_users_to)
     stats["plan"] = plan.key
     S = int(spec.n_seeds)
-    if spec.rng == "stacked":
-        uniforms = CC.policy_uniforms_dims((B, N_g, M, U_g, H), spec.seed,
-                                           S, spec.best_of)
+    uniforms = CC.policy_uniforms_dims((B, N_g, M, U_g, H), spec.seed,
+                                       S, spec.best_of)
 
     #: (axis slices to a bucket's padded sizes) per uniform tensor, in
-    #: ``policy_uniforms`` order — axis 0 here is the per-element trial/
-    #: seed axis; the batched tensors shift every axis right by one
-    _CUTS = (((1, "N"),), ((1, "N"), (2, "U")), ((1, "N"),),
-             ((1, "N"), (2, "U")), ((1, "N"),), ((1, "N"),), ((1, "U"),))
+    #: ``policy_uniforms`` order, axis 0 being the batch axis
+    _CUTS = (((2, "N"),), ((2, "N"), (3, "U")), ((2, "N"),),
+             ((2, "N"), (3, "U")), ((2, "N"),), ((2, "N"),), ((2, "U"),))
 
     results = {p: [None] * B for p in CC.OFFLINE_POLICIES}
     lp_obj = [None] * B
@@ -443,28 +380,13 @@ def _run_policy(spec: GridSpec, mesh, stats):
                                   pad_to=(Nb, Ub))
         gat = CC.gat_grid_policies(stacked, spec.seed, spec.episodes)
 
-        def cut(u, dims, off=0):
-            return _fit_axes(u, *((ax + off, {"N": Nb, "U": Ub}[d])
-                                  for ax, d in dims))
-
-        if spec.rng == "stacked":
-            args = ((stacked.data,)
-                    + tuple(cut(u[idx], dims, off=1)
-                            for u, dims in zip(uniforms, _CUTS))
-                    + (gat[0], gat[1], gat[2]))
-        else:
-            def args(take, idx=idx, data=stacked.data, gat=gat, cut=cut):
-                per = [CC.policy_uniforms_dims(
-                    (None, N_g, M, U_g, H),
-                    _element_key(spec.seed, idx[j]), S, spec.best_of)
-                    for j in take]
-                us = tuple(np.stack([cut(p[t], dims) for p in per])
-                           for t, dims in enumerate(_CUTS))
-                return ((_take_rows(data, take),) + us
-                        + tuple(_take_rows(g, take) for g in gat))
+        sizes = {"N": Nb, "U": Ub}
+        args = ((stacked.data,)
+                + tuple(_fit_axes(u[idx], *((ax, sizes[d]) for ax, d in dims))
+                        for u, dims in zip(uniforms, _CUTS))
+                + (gat[0], gat[1], gat[2]))
         fn = _compile("policy", mesh, 11, _policy_inner(spec),
-                      int(spec.pdhg_iters), S, spec.lp_backend,
-                      bool(spec.diagnostics))
+                      int(spec.pdhg_iters), S, bool(spec.diagnostics))
         out = _run_chunks(spec, mesh, fn, args, len(idx), stats,
                           bucket_key=bucket.key)
         for j, i in enumerate(idx):
@@ -498,11 +420,9 @@ def _policy_inner(spec: GridSpec):
         from repro.core.cocar import _policy_kernel
 
         iters, n_seeds = int(spec.pdhg_iters), int(spec.n_seeds)
-        lp_backend = spec.lp_backend
         diagnostics = bool(spec.diagnostics)
         return jax.vmap(
             lambda *a: _policy_kernel(*a, iters, n_seeds,
-                                      backend=lp_backend,
                                       diagnostics=diagnostics))
     return make
 
@@ -599,7 +519,6 @@ def run_grid(spec: GridSpec) -> GridResult:
     if spec.kind not in GRID_KINDS:
         raise ValueError(f"unknown grid kind {spec.kind!r}; "
                          f"one of {GRID_KINDS}")
-    _check_rng(spec)
     if spec.kind == "online":
         if spec.jobs is None or spec.ocfg is None:
             raise ValueError("online grids need spec.jobs and spec.ocfg")
@@ -609,11 +528,11 @@ def run_grid(spec: GridSpec) -> GridResult:
         raise ValueError(f"{spec.kind} grids need spec.insts")
 
     mesh = _mesh_of(spec)
-    stats = {"kind": spec.kind, "backend": spec.backend,
+    stats = {"kind": spec.kind,
              "devices": 1 if mesh is None else int(mesh.devices.size)}
     runner = {"offline": _run_offline, "policy": _run_policy,
               "online": _run_online}[spec.kind]
-    with OT.TRACER.span("run_grid", kind=spec.kind, backend=spec.backend,
+    with OT.TRACER.span("run_grid", kind=spec.kind, mesh=mesh is not None,
                         devices=stats["devices"],
                         diagnostics=bool(spec.diagnostics)) as sp:
         results = runner(spec, mesh, stats)

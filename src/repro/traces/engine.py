@@ -606,8 +606,7 @@ def grid_payloads(jobs, ocfg):
     return payloads
 
 
-def run_online_grid(jobs, ocfg, backend: str = "vmap",
-                    devices: int = None, chunk_size: int = 0,
+def run_online_grid(jobs, ocfg, devices: int = None, chunk_size: int = 0,
                     diagnostics: bool = False):
     """Run many (cfg, trace, algo, seed) scenarios in one vmapped scan
     dispatch per shape bucket, via the ``repro.scale`` grid executor.
@@ -617,13 +616,13 @@ def run_online_grid(jobs, ocfg, backend: str = "vmap",
     accepts) or ``trace`` (a Trace; the default workload when neither is
     given) and ``seed``.  Heterogeneous (n_bs, n_models, n_slots) grids
     are bucketed by shape — each bucket is one dispatch.
-    ``backend="sharded"`` partitions every bucket's batch across a
-    ``devices``-wide host mesh; ``chunk_size`` streams it in bounded
-    chunks.  Returns one summary dict per job, in order.
+    ``devices=k`` partitions every bucket's batch across a k-device host
+    mesh (``None``: one device, no mesh); ``chunk_size`` streams it in
+    bounded chunks.  Returns one summary dict per job, in order.
     """
     from repro.scale import GridSpec, run_grid
 
     spec = GridSpec(kind="online", jobs=list(jobs), ocfg=ocfg,
-                    backend=backend, devices=devices,
-                    chunk_size=chunk_size, diagnostics=diagnostics)
+                    devices=devices, chunk_size=chunk_size,
+                    diagnostics=diagnostics)
     return run_grid(spec).results

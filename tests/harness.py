@@ -7,17 +7,22 @@ metrics* — plain float reductions whose summation order may differ —
 agree to 1e-9.  This module is the single home of that contract:
 instance builders (``make_instance``, ``tiny_instance``, heterogeneous
 grids), the identity assertions (``assert_decisions_identical``,
-``assert_same_offline``, ``assert_obj_close``), and the rounding
+``assert_same_offline``, ``assert_obj_close``), the rounding
 certificates (``decision_margin``, ``threshold_shift_certificate``)
-that make the fused mixed-precision LP backend's decision identity
-checkable rather than merely observed.
+that make decision identity under a perturbed fractional solution
+checkable rather than merely observed, and the offline grid's NumPy
+host composition (``offline_grid_host``) with the bridge to the
+benchmark's independent f64 LP (``reference_window``).
 
 Used by tests/test_offline_batched.py, tests/test_baselines_device.py,
-tests/test_scale.py, tests/test_pdhg_fused.py, and
-benchmarks/bench_lp.py.
+tests/test_scale.py, tests/test_batched_lp.py and chip_smoke.py.
 """
+from types import SimpleNamespace
+
 import numpy as np
 
+from repro.core import cocar as CC
+from repro.core import lp as LP
 from repro.core.jdcr import JDCRInstance, tree_sum
 from repro.mec.scenario import MECConfig, Scenario, stack_instances
 
@@ -71,6 +76,34 @@ def padded_stack(spec):
     their true shapes plus the max-padded :class:`StackedWindows`."""
     insts = hetero_insts(spec)
     return insts, stack_instances(insts)
+
+
+def reference_window(inst):
+    """The arrays ``chipbench/reference/offline.py::solve_lp`` reads,
+    taken from ``inst``'s own arrays: its NumPy f64 PDHG, which imports
+    nothing of the program, then solves the same window."""
+    return SimpleNamespace(
+        N=inst.N, M=inst.M, U=inst.U, H=inst.H, m_u=np.asarray(inst.m_u),
+        sizes=inst.sizes, prec_u=inst.prec[inst.m_u, 1:],
+        T=inst.e2e_latency(), L=inst.load_latency(), R=inst.R,
+        ddl=inst.ddl, s_u=inst.s_u)
+
+
+# ---------------------------------------------------------------------------
+# the offline grid composed on the host
+# ---------------------------------------------------------------------------
+
+def offline_grid_host(insts, seed=0, pdhg_iters=4000, best_of=8,
+                      n_seeds=1):
+    """``cocar_grid``'s computation with NumPy round + repair: stack the
+    windows, draw the shared uniforms, solve the batched LP on the
+    device, then per-(window, seed, trial) NumPy loops.  Returns
+    ``results[b][s] = (x, A, info)`` at true shapes."""
+    stacked = stack_instances(list(insts))
+    u_cat, u_phi = CC.offline_uniforms(stacked, seed, n_seeds, best_of)
+    res = LP.solve_lp_pdhg_batched(stacked.data, iters=pdhg_iters)
+    return CC.offline_pipeline_host(stacked, res.x, res.A, u_cat, u_phi,
+                                    n_seeds=n_seeds)
 
 
 # ---------------------------------------------------------------------------
@@ -130,13 +163,11 @@ def decision_margin(x_frac, A_frac, onehot_mu, u_cat, u_phi):
     compares ``u_cat`` against partial sums of the normalized x†[n,m,:],
     the Bernoulli routing draw compares ``u_phi`` against
     φ = clip(A†/x_a, 0, 1).  A perturbed fractional solution (e.g. the
-    fused mixed-precision LP backend's, within ``gap`` of the reference)
+    device pipeline's against an independent f64 solve, within ``gap``)
     moves each threshold by O(gap / min-normalizer); decisions therefore
-    cannot flip while the reported margins stay far above that.  This is
-    the certificate ``benchmarks/bench_lp.py`` records next to the
-    measured fused-vs-reference gap and ``tests/test_pdhg_fused.py``
-    asserts on — turning "decisions happened to match" into "decisions
-    had slack to spare".
+    cannot flip while the reported margins stay far above that.
+    ``tests/test_offline_batched.py`` asserts on it — turning "decisions
+    happened to match" into "decisions had slack to spare".
 
     Returns ``{"cat": float, "phi": float, "min": float}`` (each the
     minimum over all trials and entries; padded users, whose ``phi``
@@ -155,7 +186,7 @@ def decision_margin(x_frac, A_frac, onehot_mu, u_cat, u_phi):
             "min": min(margin_cat, margin_phi)}
 
 
-def threshold_shift_certificate(x_ref, A_ref, x_pal, A_pal, onehot_mu,
+def threshold_shift_certificate(x_ref, A_ref, x_alt, A_alt, onehot_mu,
                                 u_cat, u_phi):
     """Per-comparison certificate that two fractional solutions round to
     identical decisions under the given uniforms.
@@ -181,7 +212,7 @@ def threshold_shift_certificate(x_ref, A_ref, x_pal, A_pal, onehot_mu,
     u_cat = np.asarray(u_cat, np.float64)
     u_phi = np.asarray(u_phi, np.float64)
     cums_r, phi_r = _thresholds(x_ref, A_ref, onehot_mu)
-    cums_p, phi_p = _thresholds(x_pal, A_pal, onehot_mu)
+    cums_p, phi_p = _thresholds(x_alt, A_alt, onehot_mu)
     user_mask = onehot_mu.sum(-1) > 0
 
     m_cat = np.abs(u_cat[..., None] - cums_r)

@@ -1,7 +1,11 @@
 """Batched PDHG + vectorized rounding: the one-dispatch path must agree
-with the per-instance oracles (scipy objectives, scalar rounding stats)."""
+with the per-instance oracles (scipy objectives, the benchmark's
+independent f64 PDHG, scalar rounding stats), and padding stays inert."""
+import harness
 import numpy as np
 import pytest
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import lp as LP
 from repro.core.cocar import cocar_windows_batched
@@ -112,11 +116,67 @@ def test_reference_kernel_lowers_without_dot_general(diagnostics):
             lambda a: jax.ShapeDtypeStruct((1,) + np.shape(a), np.float64),
             data)
         fn = jax.vmap(functools.partial(
-            LP._lp_solve_kernel, iters=4000, backend="reference",
-            diagnostics=diagnostics))
+            LP._pdhg_kernel, iters=4000, diagnostics=diagnostics))
         text = jax.jit(fn).lower(spec).as_text()
     assert "while" in text
     assert "dot_general" not in text
+
+
+@pytest.mark.parametrize("shape", [
+    # (seed, users, BSs, models, iterations, padded BSs)
+    (2, 60, 3, 4, 400, 0),
+    (0, 600, 5, 8, 4000, 0),            # Sec. VII-A, the benchmark's size
+    (1, 200, 3, 8, 1000, 2),
+], ids=["small", "sec7a", "padded_bs"])
+def test_reference_kernel_matches_independent_f64(shape):
+    """``_pdhg_kernel`` against the benchmark's NumPy f64 PDHG
+    (``chipbench/reference/offline.py::solve_lp``, which imports nothing
+    of the program), fed the instance's own arrays: the same iterate to
+    1e-10.  With padded base stations, the padded solve's real rows match
+    the unpadded reference solve and the padded rows hold no mass."""
+    import jax
+
+    from chipbench.reference.offline import solve_lp
+
+    seed, n_users, n_bs, n_models, iters, pad_bs = shape
+    inst = make_instance(seed=seed, n_users=n_users, n_bs=n_bs,
+                         n_models=n_models)
+    stacked = stack_instances([inst], pad_to=(n_bs + pad_bs, n_users))
+    data = type(stacked.data)(*(v[0] for v in stacked.data))
+    with jax.enable_x64(True):
+        x, A = (np.asarray(v) for v in LP._pdhg_kernel(data, iters))
+    x_r, A_r = solve_lp(harness.reference_window(inst), iters)
+    assert float(np.abs(x[:n_bs] - x_r).max()) < 1e-10
+    assert float(np.abs(A[:n_bs] - A_r).max()) < 1e-10
+    assert (A[n_bs:] == 0.0).all()
+
+
+@pytest.mark.parametrize("diagnostics", [False, True],
+                         ids=["diag_off", "diag_on"])
+@settings(max_examples=8, deadline=None)
+@given(n_users=st.integers(8, 20), n_bs=st.integers(2, 4),
+       pad_bs=st.integers(1, 3), pad_users=st.integers(1, 8),
+       seed=st.integers(0, 3))
+def test_reference_padding_is_exactly_inert(diagnostics, n_users, n_bs,
+                                            pad_bs, pad_users, seed):
+    """Padded base-station rows AND padded user columns of the reference
+    kernel's A stay exactly 0.0 (zero step sizes in tau_A, zero
+    objective and one-hot rows), with the diagnostics tap off or on, and
+    the primal stays finite in [0, 1]."""
+    import jax
+
+    inst = make_instance(seed=seed, n_users=n_users, n_bs=n_bs)
+    stacked = stack_instances([inst], pad_to=(n_bs + pad_bs,
+                                              n_users + pad_users))
+    data = type(stacked.data)(*(v[0] for v in stacked.data))
+    with jax.enable_x64(True):
+        out = LP._pdhg_kernel(data, 48, diagnostics=diagnostics,
+                              diag_stride=16)
+        x, A = np.asarray(out[0]), np.asarray(out[1])
+    assert (A[inst.N:] == 0.0).all()
+    assert (A[:, inst.U:] == 0.0).all()
+    assert np.isfinite(x).all() and (x >= 0).all() and (x <= 1).all()
+    assert np.isfinite(A).all()
 
 
 def test_round_solution_batch_shapes_and_marginals():

@@ -3,8 +3,8 @@
 The chip's compiler refuses what interpret mode and the CPU accept: a
 scatter or an unsupported reshape inside a Mosaic kernel, float64 in a
 kernel, a program that outgrows the chip's 16 GB.  These tests compile
-the kernels of the main path at their real sizes for one v5e chip — the
-fused PDHG sweep at the paper's Sec. VII-A window, and qwen1.5-0.5b and
+the programs of the main path at their real sizes for one v5e chip — the
+offline pipeline at the paper's Sec. VII-A window, and qwen1.5-0.5b and
 Moonlight-16B-A3B prefill and decode at their published widths — and
 Mixtral's train and serve steps sharded over four chips, so such a
 refusal fails here, before any chip time is spent.  Nothing runs: a compile that passes
@@ -59,28 +59,29 @@ def _fits_one_chip(compiled):
     return used
 
 
-def test_pdhg_pallas_sweep_compiles_for_v5e(one_chip):
-    """The f32 sweep of the fused PDHG engine as a Mosaic kernel, vmapped
-    over windows and traced under x64 as the offline pipeline calls it,
-    at N=5, U=600, M=8."""
-    from repro.core import lp as LP
-    from repro.kernels import pdhg_fused as PF
+def test_cocar_offline_pipeline_compiles_for_v5e(one_chip):
+    """``cocar_offline_pipeline``, the program the ``sec7a-offline`` cell
+    runs, as the grid executor compiles it for one window with no mesh,
+    at Sec. VII-A shapes (N=5, U=600, M=8, 4000 PDHG iterations, best of
+    8 rounding trials), traced under x64."""
+    from repro.core.rounding import draw_rounding_uniforms
     from repro.mec.scenario import MECConfig, Scenario, stack_instances
+    from repro.scale import GridSpec
+    from repro.scale.executor import _offline_inner
 
     sc = Scenario(MECConfig())
-    insts = [sc.instance(w, sc.empty_cache()) for w in range(2)]
-    data = _on(one_chip, jax.tree.map(
-        lambda a: np.asarray(a, np.float32), stack_instances(insts).data))
-    assert isinstance(data, LP.PDHGData)
-
-    def sweep(d):
-        _, state = PF._init_state(d, jnp.float32)
-        return PF._pallas_phase(d, state, 3 * PF.PALLAS_BLOCK + 3,
-                                jnp.float32, interpret=False)
-
+    inst = sc.instance(0, sc.empty_cache())
+    spec = GridSpec(kind="offline", insts=[inst], seed=0, n_seeds=1,
+                    best_of=8, pdhg_iters=4000)
     with jax.enable_x64(True):
-        compiled = jax.jit(jax.vmap(sweep)).lower(data).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        args = (stack_instances([inst]).data,
+                *draw_rounding_uniforms(0, 8, inst.N, inst.M, inst.U,
+                                        inst.H, batch=1))
+        args = _on(one_chip, jax.tree.map(np.asarray, args))
+        fn = jax.jit(_offline_inner(spec)(), donate_argnums=(0, 1, 2))
+        lowered = fn.lower(*args)
+        compiled = lowered.compile()
+    assert "cocar_offline_pipeline" in lowered.as_text()
     _fits_one_chip(compiled)
 
 

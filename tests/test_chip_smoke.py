@@ -1,7 +1,6 @@
 """``chip_smoke.py`` off the chip: it refuses to run without a TPU, and
 each phase's path and checks run at a tiny size on the CPU, so the
-script cannot rot between chip runs.  Only the TPU-only check — that the
-pallas LP backend compiled to a Mosaic kernel — is stubbed."""
+script cannot rot between chip runs."""
 import json
 import os
 import subprocess
@@ -35,8 +34,7 @@ def test_exits_nonzero_without_a_tpu():
 
 @pytest.mark.parametrize("phase", ["offline", "online", "serving",
                                    "sharded"])
-def test_phase_runs_at_tiny_size(phase, monkeypatch, capsys):
-    monkeypatch.setattr(CS, "_has_mosaic_kernel", lambda *a: True)
+def test_phase_runs_at_tiny_size(phase, capsys):
     if phase == "offline":
         CS.phase_offline(0, cfg=MECConfig(n_users=30), pdhg_iters=300,
                          best_of=3)
@@ -55,6 +53,4 @@ def test_phase_runs_at_tiny_size(phase, monkeypatch, capsys):
 def _well_formed(line):
     """Every printed phase line names its phase and reports finite
     set-up and warm seconds."""
-    if "setup_s" not in line:
-        return line.get("identical_across_backends") is True
     return line["setup_s"] >= 0 and line["warm_s"] >= 0

@@ -171,26 +171,6 @@ def test_moe_gmm_interpret_explicit():
     np.testing.assert_allclose(out, r, atol=2e-4, rtol=2e-4)
 
 
-@pytest.mark.slow_compile
-def test_pdhg_fused_interpret_explicit():
-    """The fused PDHG kernel honours interpret=True and agrees with the
-    scan engine (same _fused_step source) on a small instance.  The
-    kernel body is dtype-generic, so interpret mode runs it in f64 —
-    which pins the engines to f64 FMA noise."""
-    from harness import make_instance
-    from repro.core import lp as LP
-    from repro.kernels import pdhg_fused as PF
-    inst = make_instance(seed=6, n_users=16, n_bs=2)
-    with jax.enable_x64(True):
-        data = jax.tree.map(jnp.asarray, LP.pdhg_data(inst))
-        _, st = PF._init_state(data, jnp.float64)
-        ss = PF._scan_phase(data, st, 24, jnp.float64)
-        sp = PF._pallas_phase(data, st, 24, jnp.float64, block=8,
-                              interpret=True)
-    for a, b in zip(sp, ss):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-12)
-
-
 def test_flash_matches_model_attention():
     """The kernel agrees with the model's blocked-attention path."""
     from repro.models.flash import flash_attention as model_flash

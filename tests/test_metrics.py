@@ -292,7 +292,7 @@ def test_executor_watermarks_decision_inert():
 
     insts = [make_instance(seed=s, n_users=20) for s in (0, 1)]
     kw = dict(kind="offline", insts=insts, seed=0, n_seeds=1, best_of=2,
-              pdhg_iters=150, backend="vmap")
+              pdhg_iters=150)
     off = run_grid(GridSpec(**kw))
     t0 = time.perf_counter()
     on = run_grid(GridSpec(**kw, diagnostics=True))

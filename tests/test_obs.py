@@ -6,8 +6,8 @@ Three layers, mirroring the subsystem:
     retrace accounting, and run provenance (no jax required);
   * jit-safe diagnostics taps — the solver/engine curves must be
     DECISION-INERT: bit-identical x/A/QoE with the tap on or off, on the
-    reference kernel, the fused kernel's scan engine, the offline and
-    policy device grids, the online scan, and the sharded executor;
+    reference kernel, the offline and policy device grids, the online
+    scan, and the sharded executor;
   * regression invariants — repeat sweeps retrace nothing
     (compile-cache deltas stay zero), ``solve_lp_pdhg`` carries an
     honest ``converged`` flag, and ``scripts/report.py`` renders the
@@ -238,32 +238,13 @@ def test_reference_diag_inert_and_curves():
     assert summ["n_samples"] == 4
 
 
-def test_fused_scan_diag_inert():
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core import lp as LP
-    from repro.kernels.pdhg_fused import pdhg_fused
-
-    inst = make_instance(n_users=24)
-    data = jax.tree_util.tree_map(jnp.asarray, LP.pdhg_data(inst))
-    x0, A0 = pdhg_fused(data, ITERS, engine="scan")
-    out = pdhg_fused(data, ITERS, engine="scan", diagnostics=True,
-                     diag_stride=40)
-    np.testing.assert_array_equal(np.asarray(x0), np.asarray(out[0]))
-    np.testing.assert_array_equal(np.asarray(A0), np.asarray(out[1]))
-    d = out[2]
-    assert float(d["polish_delta"]) >= 0.0
-    assert d["primal_res"].shape == d["iters"].shape
-
-
 def test_offline_grid_diag_inert():
     from repro.core.cocar import cocar_grid
 
     insts = [make_instance(seed=s, n_users=20) for s in (0, 1)]
     kw = dict(seed=0, pdhg_iters=ITERS, best_of=2, n_seeds=2)
-    off = cocar_grid(insts, backend="device", **kw)
-    on = cocar_grid(insts, backend="device", diagnostics=True, **kw)
+    off = cocar_grid(insts, **kw)
+    on = cocar_grid(insts, diagnostics=True, **kw)
     assert_same_offline(off, on)
     summ = on[0][0][2]["lp_diag"]["summary"]
     assert {"final_residual", "converged", "iters_to_tol"} <= set(summ)
@@ -274,8 +255,8 @@ def test_sharded_grid_diag_inert():
 
     insts = [make_instance(seed=s, n_users=20) for s in (0, 1, 2)]
     kw = dict(seed=0, pdhg_iters=ITERS, best_of=2, n_seeds=1)
-    off = cocar_grid(insts, backend="device", **kw)
-    on = cocar_grid(insts, backend="sharded", diagnostics=True, **kw)
+    off = cocar_grid(insts, **kw)
+    on = cocar_grid(insts, devices=1, diagnostics=True, **kw)
     assert_same_offline(off, on)
     assert "lp_diag" in on[0][0][2]
 
@@ -285,7 +266,7 @@ def test_policy_grid_diag_inert():
 
     insts = [make_instance(seed=s, n_users=20) for s in (0, 1)]
     kw = dict(kind="policy", insts=insts, seed=0, n_seeds=1, best_of=2,
-              pdhg_iters=ITERS, episodes=4, backend="vmap")
+              pdhg_iters=ITERS, episodes=4)
     off = run_grid(GridSpec(**kw))
     on = run_grid(GridSpec(**kw, diagnostics=True))
     for p in off.results:
@@ -328,8 +309,8 @@ def test_online_grid_sharded_diag_inert():
                                                    seed=0))
             for a in ("cocar-ol", "lfu")]
     ocfg = OnlineConfig(n_slots=10, rounds=2)
-    off = run_online_grid(jobs, ocfg, backend="vmap")
-    on = run_online_grid(jobs, ocfg, backend="sharded", diagnostics=True)
+    off = run_online_grid(jobs, ocfg)
+    on = run_online_grid(jobs, ocfg, devices=1, diagnostics=True)
     for a, b in zip(off, on):
         np.testing.assert_array_equal(a["slot_qoe"], b["slot_qoe"])
         assert b["diagnostics"]["hit_rate"].shape == (10,)
@@ -344,7 +325,7 @@ def test_repeat_sweep_zero_retraces():
 
     insts = [make_instance(seed=s, n_users=20) for s in (0, 1)]
     kw = dict(seed=0, pdhg_iters=ITERS, best_of=2, n_seeds=1,
-              backend="device", diagnostics=True)
+              diagnostics=True)
     cocar_grid(insts, **kw)                      # warm every cache
     snap = retrace_snapshot()
     again = cocar_grid(insts, **kw)
@@ -358,8 +339,7 @@ def test_executor_stats_spans():
 
     insts = [make_instance(seed=s, n_users=20) for s in (0, 1)]
     res = run_grid(GridSpec(kind="offline", insts=insts, seed=0,
-                            n_seeds=1, best_of=2, pdhg_iters=ITERS,
-                            backend="vmap"))
+                            n_seeds=1, best_of=2, pdhg_iters=ITERS))
     assert res.stats["seconds"] > 0.0
     assert res.stats["retraces"] >= 0
     assert res.stats["chunks"] >= 1

@@ -1,9 +1,15 @@
 """The fused offline pipeline (LP → round → repair → metrics, one device
 dispatch) vs the NumPy reference: decision-identical equivalence on whole
 grids, repair edge cases asserted on BOTH paths, and the deterministic
-reduction (`tree_sum`) invariants the equivalence rides on."""
+reduction (`tree_sum`) invariants the equivalence rides on; and the
+rounding-margin certificate against an independent f64 LP, with the
+uniform-consumption locality the grid executor's slicing relies on."""
+import harness
 import numpy as np
+import pytest
 from harness import make_instance, tiny_instance
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import cocar as CC
 from repro.core import lp as LP
@@ -170,10 +176,14 @@ def test_reroute_recovers_unserved_user_at_feasible_bs():
 # ---------------------------------------------------------------------------
 
 HETERO = [(0, 40, 3), (1, 50, 4), (2, 35, 3)]
+#: the paper's Sec. VII-A window: N=5, U=600, M=8
+SEC7A = [(0, 600, 5)]
 
 
-def _device_vs_reference(n_seeds, best_of, iters=500):
-    insts = [make_instance(seed=s, n_users=u, n_bs=n) for s, u, n in HETERO]
+def _device_vs_reference(n_seeds, best_of, iters=500, spec=HETERO,
+                         n_models=4):
+    insts = [make_instance(seed=s, n_users=u, n_bs=n, n_models=n_models)
+             for s, u, n in spec]
     stacked = stack_instances(insts)
     u_cat, u_phi = CC.offline_uniforms(stacked, 7, n_seeds, best_of)
     dev = CC.offline_pipeline_device(stacked, u_cat, u_phi,
@@ -184,10 +194,14 @@ def _device_vs_reference(n_seeds, best_of, iters=500):
     return insts, devu, host
 
 
-def test_device_pipeline_matches_reference_on_hetero_grid():
-    """Identical cache/routing decisions on a padded heterogeneous stack,
-    objectives and window metrics within 1e-9, all outputs feasible."""
-    insts, devu, host = _device_vs_reference(n_seeds=2, best_of=4)
+@pytest.mark.parametrize("spec,n_models", [(HETERO, 4), (SEC7A, 8)],
+                         ids=["hetero", "sec7a"])
+def test_device_pipeline_matches_reference_on_hetero_grid(spec, n_models):
+    """Identical cache/routing decisions on a padded heterogeneous stack
+    and on one Sec. VII-A window, objectives and window metrics within
+    1e-9, all outputs feasible."""
+    insts, devu, host = _device_vs_reference(n_seeds=2, best_of=4,
+                                             spec=spec, n_models=n_models)
     for inst, per_dev, per_host in zip(insts, devu, host):
         for (xd, Ad, idv), (xh, Ah, ih) in zip(per_dev, per_host):
             assert np.array_equal(xd, xh)
@@ -256,15 +270,93 @@ def test_sweep_seeds_axis():
 
 
 def test_cocar_grid_host_backend_matches_shapes():
-    """The host backend returns the same result structure (it is the same
-    algorithm, looped on the host against its own LP solve)."""
+    """The host composition returns ``cocar_grid``'s result structure (it
+    is the same algorithm, looped on the host against its own LP solve)
+    and its decisions."""
     insts = [make_instance(seed=s, n_users=u, n_bs=n)
              for s, u, n in HETERO[:2]]
-    grid = CC.cocar_grid(insts, seed=0, pdhg_iters=300, best_of=2,
-                         n_seeds=2, backend="host")
+    kw = dict(seed=0, pdhg_iters=300, best_of=2, n_seeds=2)
+    grid = harness.offline_grid_host(insts, **kw)
+    harness.assert_same_offline(CC.cocar_grid(insts, **kw), grid)
     assert len(grid) == 2 and len(grid[0]) == 2
     for inst, per_seed in zip(insts, grid):
         for x, A, info in per_seed:
             assert x.shape == (inst.N, inst.M, inst.H + 1)
             assert check_feasible(inst, x, A)["ok"]
             assert info["lp_obj"] > 0
+
+
+# ---------------------------------------------------------------------------
+# the rounding-margin certificate against an independent f64 LP
+# ---------------------------------------------------------------------------
+
+def test_rounding_margin_certifies_decision_identity():
+    """The device pipeline's fractional solution must sit far closer to
+    the benchmark's independent NumPy f64 PDHG
+    (``chipbench/reference/offline.py::solve_lp``) than any uniform sits
+    to its rounding threshold — decisions then *cannot* differ from the
+    reference's, rather than merely not differing on this draw."""
+    from chipbench.reference.offline import solve_lp
+
+    iters, n_seeds, best_of = 300, 2, 3
+    insts, stacked = harness.padded_stack(HETERO)
+    u_cat, u_phi = CC.offline_uniforms(stacked, 7, n_seeds, best_of)
+    dev = CC.offline_pipeline_device(stacked, u_cat, u_phi,
+                                     pdhg_iters=iters, n_seeds=n_seeds)
+    for i, inst in enumerate(insts):
+        N, U = inst.N, inst.U
+        x_r, A_r = solve_lp(harness.reference_window(inst), iters)
+        x_d, A_d = dev["x_frac"][i, :N], dev["A_frac"][i, :N, :U]
+        gap = max(float(np.abs(x_d - x_r).max()),
+                  float(np.abs(A_d - A_r).max()))
+        oh = inst.onehot_mu()
+        uc, up = u_cat[i, :, :N], u_phi[i, :, :N, :U]
+        m = harness.decision_margin(x_r, A_r, oh, uc, up)
+        assert m["min"] > 0
+        assert gap < m["min"] / 10.0, (i, gap, m)
+        cert = harness.threshold_shift_certificate(x_r, A_r, x_d, A_d, oh,
+                                                   uc, up)
+        assert cert["certified"], (i, cert)
+        assert cert["headroom"] > 10.0, (i, cert)
+
+
+@settings(max_examples=10, deadline=None)
+@given(n=st.integers(2, 4), m=st.integers(2, 4), u=st.integers(3, 8),
+       h=st.integers(1, 3), t=st.integers(2, 4), row=st.integers(0, 3),
+       trial=st.integers(0, 3), seed=st.integers(0, 100))
+def test_rounding_uniform_consumption_is_local(n, m, u, h, t, row, trial,
+                                               seed):
+    """Alg. 1 rounding consumes uniforms positionally: perturbing the
+    uniforms of one trial / one BS row changes no other trial's or row's
+    decisions.  This locality is what lets the scale executor draw
+    uniforms once at the global max shape and slice them per bucket."""
+    row, trial = row % n, trial % t
+    rng = np.random.default_rng(seed)
+    x_frac = rng.random((n, m, h + 1))
+    A_frac = rng.random((n, u, h))
+    m_u = rng.integers(0, m, size=u)
+    onehot = np.zeros((u, m))
+    onehot[np.arange(u), m_u] = 1.0
+    u_cat = rng.random((t, n, m))
+    u_phi = rng.random((t, n, u, h))
+    x0, A0 = round_from_uniforms(x_frac, A_frac, onehot, u_cat, u_phi)
+
+    # perturb every uniform of one trial: other trials bit-unchanged
+    u_cat2, u_phi2 = u_cat.copy(), u_phi.copy()
+    u_cat2[trial] = rng.random((n, m))
+    u_phi2[trial] = rng.random((n, u, h))
+    x1, A1 = round_from_uniforms(x_frac, A_frac, onehot, u_cat2, u_phi2)
+    others = [tt for tt in range(t) if tt != trial]
+    harness.assert_decisions_identical(x0[others], A0[others],
+                                       x1[others], A1[others],
+                                       msg="(trial locality)")
+
+    # perturb one BS row's uniforms: other rows bit-unchanged
+    u_cat3, u_phi3 = u_cat.copy(), u_phi.copy()
+    u_cat3[:, row] = rng.random((t, m))
+    u_phi3[:, row] = rng.random((t, u, h))
+    x2, A2 = round_from_uniforms(x_frac, A_frac, onehot, u_cat3, u_phi3)
+    keep = [nn for nn in range(n) if nn != row]
+    harness.assert_decisions_identical(x0[:, keep], A0[:, keep],
+                                       x2[:, keep], A2[:, keep],
+                                       msg="(row locality)")

@@ -1,10 +1,10 @@
 """The sharded grid executor (``repro.scale``): bucket planning, the
 bucketed-padding == max-padding decision identity for all five offline
 policies AND the online scan engine, chunked streaming, the shard_map
-path (on a 1-device mesh — the multi-device run is exercised by
-``benchmarks/bench_scale.py`` under
-``--xla_force_host_platform_device_count=8`` in CI), mesh validation,
-and jit-cache stability across repeated sweeps."""
+path (``devices=1``, a 1-device mesh — the multi-device run is exercised
+by ``benchmarks/bench_scale.py`` under
+``--xla_force_host_platform_device_count=8`` in CI), spec and mesh
+validation, and jit-cache stability across repeated sweeps."""
 import harness
 import numpy as np
 import pytest
@@ -15,7 +15,6 @@ from repro.core.online import OnlineConfig
 from repro.mec.scenario import MECConfig, stack_instances
 from repro.scale import GridSpec, plan_buckets, run_grid
 from repro.scale.executor import compiled_cache_stats
-from repro.traces import engine as E
 from repro.traces.registry import make_trace
 
 #: heterogeneous (seed, n_users, n_bs) grid shared by the identity tests
@@ -107,8 +106,7 @@ S, BO, ITERS = 2, 2, 250
 
 def offline_grid(**kw):
     spec = dict(kind="offline", insts=hetero_insts(), seed=0, n_seeds=S,
-                best_of=BO, pdhg_iters=ITERS, backend="vmap",
-                max_buckets=1)
+                best_of=BO, pdhg_iters=ITERS, max_buckets=1)
     spec.update(kw)
     return run_grid(GridSpec(**spec))
 
@@ -144,7 +142,7 @@ def test_offline_sharded_matches_vmap():
     plain vmap dispatch (the multi-device identity is gated in CI by
     bench_scale under 8 forced host devices)."""
     ref = offline_grid(max_buckets=2)
-    out = offline_grid(max_buckets=2, backend="sharded", devices=1)
+    out = offline_grid(max_buckets=2, devices=1)
     assert out.stats["devices"] == 1
     assert_same_offline(ref.results, out.results)
 
@@ -159,38 +157,6 @@ def test_offline_matches_legacy_single_dispatch():
                                      pdhg_iters=ITERS, n_seeds=S)
     legacy = CC._unstack_device(stacked, dev, S)
     assert_same_offline(legacy, offline_grid().results)
-
-
-def test_offline_per_element_rng_layout_invariant():
-    """The O(chunk)-memory ``per_element`` scheme must be invariant to
-    bucketing, chunking, AND the shard_map backend (its draws are keyed
-    on the original grid index, so the layout cannot reach them)."""
-    ref = offline_grid(rng="per_element")
-    for kw in (dict(max_buckets=3), dict(chunk_size=2),
-               dict(max_buckets=2, chunk_size=2,
-                    backend="sharded", devices=1)):
-        out = offline_grid(rng="per_element", **kw)
-        assert_same_offline(ref.results, out.results)
-    for inst, per_seed in zip(hetero_insts(), ref.results):
-        for x, A, _ in per_seed:
-            assert x.shape == (inst.N, inst.M, inst.H + 1)
-    with pytest.raises(ValueError, match="unknown rng"):
-        offline_grid(rng="per-window")
-
-
-def test_policy_per_element_rng_bucket_invariant():
-    insts = hetero_insts()[:2]
-    kw = dict(kind="policy", insts=insts, seed=0, n_seeds=1, best_of=BO,
-              pdhg_iters=ITERS, episodes=5, backend="vmap",
-              rng="per_element")
-    ref = run_grid(GridSpec(**kw, max_buckets=1))
-    out = run_grid(GridSpec(**kw, max_buckets=2, chunk_size=1))
-    for p in CC.OFFLINE_POLICIES:
-        for i in range(len(insts)):
-            x1, A1, m1 = ref.results[p][i][0]
-            x2, A2, m2 = out.results[p][i][0]
-            np.testing.assert_array_equal(x1, x2, err_msg=f"{p}[{i}]")
-            np.testing.assert_array_equal(A1, A2, err_msg=f"{p}[{i}]")
 
 
 def test_compiled_cache_stable_across_repeats():
@@ -214,7 +180,7 @@ def test_compiled_cache_stable_across_repeats():
 def test_policy_bucketed_matches_max_padded():
     insts = hetero_insts()[:4]
     kw = dict(kind="policy", insts=insts, seed=0, n_seeds=S, best_of=BO,
-              pdhg_iters=ITERS, episodes=5, backend="vmap")
+              pdhg_iters=ITERS, episodes=5)
     ref = run_grid(GridSpec(**kw, max_buckets=1))
     out = run_grid(GridSpec(**kw, max_buckets=2))
     assert len(out.stats["plan"]) == 2
@@ -244,7 +210,7 @@ def test_policy_matches_legacy_policy_grid():
                                 gat=gat)
     res = run_grid(GridSpec(kind="policy", insts=insts, seed=0, n_seeds=S,
                             best_of=BO, pdhg_iters=ITERS, episodes=5,
-                            backend="vmap", max_buckets=1))
+                            max_buckets=1))
     for p in CC.OFFLINE_POLICIES:
         for i, inst in enumerate(insts):
             for s in range(S):
@@ -253,6 +219,23 @@ def test_policy_matches_legacy_policy_grid():
                     dev[p]["x"][i, s, :inst.N], x_n)
                 np.testing.assert_array_equal(
                     dev[p]["A"][i, s, :inst.N, :inst.U], A_n)
+
+
+@pytest.mark.parametrize("layout", [dict(chunk_size=2), dict(devices=1)],
+                         ids=["chunked", "devices1"])
+def test_policy_chunked_and_sharded_match_one_chunk(layout):
+    """All five policies, streamed in chunks of 2 or run under shard_map
+    on a 1-device mesh, make the decisions of one no-mesh chunk."""
+    insts = hetero_insts()[:3]
+    kw = dict(kind="policy", insts=insts, seed=0, n_seeds=S, best_of=BO,
+              pdhg_iters=ITERS, episodes=5, max_buckets=1)
+    ref = run_grid(GridSpec(**kw))
+    out = run_grid(GridSpec(**kw, **layout))
+    assert ref.stats["chunks"] == 1
+    assert out.stats["chunks"] == (2 if "chunk_size" in layout else 1)
+    for p in CC.OFFLINE_POLICIES:
+        assert_same_offline(ref.results[p], out.results[p])
+    np.testing.assert_array_equal(ref.stats["lp_obj"], out.stats["lp_obj"])
 
 
 # ---------------------------------------------------------------------------
@@ -277,8 +260,7 @@ def _online_jobs():
 
 def test_online_bucketed_grid_matches_solo_runs():
     jobs = _online_jobs()
-    res = run_grid(GridSpec(kind="online", jobs=jobs, ocfg=OCFG,
-                            backend="vmap"))
+    res = run_grid(GridSpec(kind="online", jobs=jobs, ocfg=OCFG))
     assert len(res.results) == len(jobs)
     assert len(res.stats["plan"]) == 2     # two shape buckets
     for j, g in zip(jobs, res.results):
@@ -294,10 +276,9 @@ def test_online_bucketed_grid_matches_solo_runs():
 
 def test_online_sharded_chunked_matches_vmap():
     jobs = _online_jobs()
-    ref = run_grid(GridSpec(kind="online", jobs=jobs, ocfg=OCFG,
-                            backend="vmap"))
+    ref = run_grid(GridSpec(kind="online", jobs=jobs, ocfg=OCFG))
     out = run_grid(GridSpec(kind="online", jobs=jobs, ocfg=OCFG,
-                            backend="sharded", devices=1, chunk_size=2))
+                            devices=1, chunk_size=2))
     for a, b in zip(ref.results, out.results):
         np.testing.assert_array_equal(a["slot_qoe"], b["slot_qoe"])
         np.testing.assert_array_equal(a["final_state"].lvl,
@@ -310,19 +291,26 @@ def test_online_sharded_chunked_matches_vmap():
 # spec validation + progress reporting
 # ---------------------------------------------------------------------------
 
-def test_run_grid_validates_spec():
-    with pytest.raises(ValueError, match="unknown grid kind"):
-        run_grid(GridSpec(kind="nope", insts=hetero_insts()))
-    with pytest.raises(ValueError, match="spec.insts"):
-        run_grid(GridSpec(kind="offline", insts=[]))
-    with pytest.raises(ValueError, match="spec.jobs"):
-        run_grid(GridSpec(kind="online"))
-    with pytest.raises(ValueError, match="unknown backend"):
-        run_grid(GridSpec(kind="offline", insts=hetero_insts(),
-                          backend="tpu"))
-    with pytest.raises(ValueError, match="only meaningful"):
-        run_grid(GridSpec(kind="offline", insts=hetero_insts(),
-                          backend="vmap", devices=8))
+@pytest.mark.parametrize("spec,error,match", [
+    (dict(kind="nope", insts="hetero"), ValueError, "unknown grid kind"),
+    (dict(kind="offline", insts=[]), ValueError, "spec.insts"),
+    (dict(kind="online"), ValueError, "spec.jobs"),
+    (dict(kind="offline", insts="hetero", devices=0), ValueError,
+     "mesh axes must be >= 1"),
+    (dict(kind="offline", insts="hetero", devices="visible+1"),
+     RuntimeError, "xla_force_host_platform"),
+], ids=["unknown_kind", "empty_insts", "missing_jobs", "devices_0",
+        "devices_above_visible"])
+def test_run_grid_validates_spec(spec, error, match):
+    import jax
+
+    spec = dict(spec)
+    if spec.get("insts") == "hetero":
+        spec["insts"] = hetero_insts()
+    if spec.get("devices") == "visible+1":
+        spec["devices"] = len(jax.devices()) + 1
+    with pytest.raises(error, match=match):
+        run_grid(GridSpec(**spec))
 
 
 def test_progress_callback_sees_every_chunk():
